@@ -61,6 +61,9 @@ def main() -> None:
     def on(name: str) -> bool:
         return not want or any(w in name for w in want)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     print("name,us_per_call,derived")
     if on("table1"):
         from benchmarks import table1_perf

@@ -448,8 +448,11 @@ def sharded_open_mesh8_cell(seed: int = 0) -> dict:
     """Disaggregated open-loop serving on 8 forced host devices:
     prefill and decode engines on disjoint 4-device submeshes, joined
     by mesh channels, with page migration between the pools.  Outputs
-    must match the single-host paged loop on the same arrival trace."""
+    must match the single-host paged loop on the same arrival trace.
+    The child is pinned to the CPU: its devices are forced host devices,
+    and a chip held by this process could not be shared with it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(REPO_ROOT / "src")
     out = subprocess.run(

@@ -1,11 +1,12 @@
 """Assemble, persist and load ``BENCH_<axis>.json`` reports.
 
 A report is self-describing: besides the cells it records the git SHA
-it ran at, the JAX backend, the RNG seed and the Python version, so a
-number in a months-old artifact can be traced to the exact tree and
-environment that produced it.  Metadata lookups are tolerant — a
-tarball checkout without git still benches, it just records
-``git_sha: "unknown"``.
+it ran at, the device (platform, kind and count), the RNG seed and the
+Python version, so a number in a months-old artifact can be traced to
+the exact tree and environment that produced it.  The git lookup is
+tolerant — a tarball checkout without git still benches, it just
+records ``git_sha: "unknown"`` — but a run that finds no JAX backend
+fails rather than record an unattributed report.
 """
 
 from __future__ import annotations
@@ -34,18 +35,20 @@ def _git_sha() -> str:
         return "unknown"
 
 
-def _backend() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # report assembly must not require a live backend
-        return "unavailable"
+def _device_meta() -> Dict[str, object]:
+    """The device the cells ran on, as JAX reports it.  No backend is
+    an error: a report must never be unattributed."""
+    import jax
+    devices = jax.devices()
+    return {"backend": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
 
 
 def bench_meta(*, seed: int) -> Dict[str, object]:
     return {
         "git_sha": _git_sha(),
-        "backend": _backend(),
+        **_device_meta(),
         "seed": int(seed),
         "python": sys.version.split()[0],
     }

@@ -28,10 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                              # jax >= 0.5 re-exports at top level
-    from jax import shard_map as _shard_map
-except ImportError:               # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from repro.channels.base import ChannelBase
 

@@ -169,7 +169,7 @@ class CompiledKernel:
 def codegen(ir: DaeIR, chk: CheckResult,
             plans: Dict[str, ChannelPlan], *,
             chase: Optional[ChaseSpec] = None,
-            interpret: bool = True) -> CompiledKernel:
+            interpret: bool) -> CompiledKernel:
     """Instantiate the ring kernels for a checked IR."""
     runners: List[Callable[[], Dict[str, Any]]] = []
 

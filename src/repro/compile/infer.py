@@ -56,7 +56,7 @@ def _cached_config(ir: DaeIR, interpret: bool) -> Dict:
 
 def infer_plans(ir: DaeIR, *, rif: Optional[int] = None,
                 chunk: Optional[int] = None,
-                interpret: bool = True) -> Dict[str, ChannelPlan]:
+                interpret: bool) -> Dict[str, ChannelPlan]:
     """One :class:`ChannelPlan` per load channel in ``ir``."""
     from repro.core.pipeline import plan_rif
 

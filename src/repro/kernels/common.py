@@ -14,12 +14,13 @@ fallback (typically derived from ``plan_rif``) applies.
 from __future__ import annotations
 
 import os
-from typing import Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 
 __all__ = ["cdiv", "round_up", "env_flag", "resolve_interpret",
-           "tuned_knobs", "ring_rif", "MXU_LANE", "VMEM_BYTES"]
+           "tuned_knobs", "ring_rif", "note_knobs", "KNOBS_USED",
+           "MXU_LANE", "VMEM_BYTES"]
 
 # TPU v5e hardware shape constants (see benchmarks/hw.py for the full set)
 MXU_LANE = 128          # lane dimension granularity
@@ -66,6 +67,16 @@ def ring_rif(rif: Optional[int], block_bytes: int) -> int:
     # cycle on a top-level repro.core.pipeline import
     from repro.core.pipeline import plan_rif
     return plan_rif(block_bytes).rif
+
+
+# (op, dims) -> the knobs a dispatcher last resolved for that call; read
+# by benchmarks to report what actually ran
+KNOBS_USED: Dict[Tuple[str, Tuple[int, ...]], Dict[str, Any]] = {}
+
+
+def note_knobs(op: str, dims, **knobs) -> None:
+    """Record the fully resolved knobs of one dispatch of ``op``."""
+    KNOBS_USED[(op, tuple(int(d) for d in dims))] = knobs
 
 
 def tuned_knobs(op: str, dims, dtype, interpret: bool, **defaults):

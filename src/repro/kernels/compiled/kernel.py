@@ -56,13 +56,13 @@ def _gather_kernel(addr_ref, port_hbm, out_ref, scratch, sems, *,
         src=lambda k: port_hbm.at[pl.ds(addr_ref[base + k], 1), :])
 
     def execute(k, row):
-        pl.store(out_ref, (pl.ds(k, 1), slice(None)), row)
+        out_ref[pl.ds(k, 1), :] = row
 
     access_execute([ring], chunk, execute)
 
 
 def ring_gather(port: jax.Array, addrs: jax.Array, *, chunk: int,
-                rif: int, interpret: bool = True) -> jax.Array:
+                rif: int, interpret: bool) -> jax.Array:
     """Fetch ``port[addrs]`` — ``port`` (N, W), ``addrs`` (M,) int32
     with M a multiple of ``chunk``.  Returns (M, W)."""
     m = addrs.shape[0]
@@ -101,7 +101,7 @@ def _deref_kernel(addr_ref, a_hbm, b_hbm, out_a_ref, out_b_ref,
         src=lambda k: a_hbm.at[pl.ds(addr_ref[base + k], 1), :])
 
     def land_a(k, row):
-        pl.store(out_a_ref, (pl.ds(k, 1), slice(None)), row)
+        out_a_ref[pl.ds(k, 1), :] = row
         # The landed scalar IS the next address (check guarantees the
         # true-run addresses were in range; the clip only disciplines
         # the perturbed-ghost values a real run never produces).
@@ -114,14 +114,14 @@ def _deref_kernel(addr_ref, a_hbm, b_hbm, out_a_ref, out_b_ref,
         src=lambda k: b_hbm.at[pl.ds(addr_s[k], 1), :])
 
     def land_b(k, row):
-        pl.store(out_b_ref, (pl.ds(k, 1), slice(None)), row)
+        out_b_ref[pl.ds(k, 1), :] = row
 
     access_execute([ring_b], chunk, land_b)
 
 
 def ring_deref(port_a: jax.Array, port_b: jax.Array, addrs: jax.Array,
                *, chunk: int, rif_a: int, rif_b: int, offset: int = 0,
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """Two-phase ring: ``va = a[addrs]`` then ``vb = b[va + offset]``.
     ``port_a`` is (NA, 1) int32; returns ((M, 1) int32, (M, WB))."""
     m = addrs.shape[0]
@@ -200,10 +200,8 @@ def _chase_kernel(state0_ref, port_hbm, out_addr_ref, out_val_ref,
 
     def emit(k, _):
         oa, ov = out_fn(state_at(k))
-        pl.store(out_addr_ref, (pl.ds(k, 1),),
-                 jnp.asarray(oa).astype(jnp.int32)[None])
-        pl.store(out_val_ref, (pl.ds(k, 1),),
-                 jnp.asarray(ov).astype(jnp.int32)[None])
+        out_addr_ref[pl.ds(k, 1)] = jnp.asarray(oa).astype(jnp.int32)[None]
+        out_val_ref[pl.ds(k, 1)] = jnp.asarray(ov).astype(jnp.int32)[None]
         return 0
 
     jax.lax.fori_loop(0, chunk, emit, 0)
@@ -212,7 +210,7 @@ def _chase_kernel(state0_ref, port_hbm, out_addr_ref, out_val_ref,
 def ring_chase(port: jax.Array, state0_flat: jax.Array, *, chunk: int,
                rif: int, max_steps: int, s_width: int,
                addr_fn: Callable, step_fn: Callable, out_fn: Callable,
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """Walk a dependent-load chase for M items (``state0_flat`` is the
     row-major (M*S,) int32 initial state, M a multiple of ``chunk``).
     Returns per-item ``(store_addr, store_value)`` int32 vectors."""

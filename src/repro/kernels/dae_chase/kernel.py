@@ -58,18 +58,17 @@ def _searchsorted_kernel(blk_ref, keys_ref, tiles_hbm, out_ref, scratch,
         src=lambda k: tiles_hbm.at[pl.ds(blk_ref[base + k], 1), :])
 
     def execute(k, row):
-        key = pl.load(keys_ref, (pl.ds(k, 1),))            # (1,)
+        key = keys_ref[pl.ds(k, 1)]                        # (1,)
         within = jnp.sum((row <= key[0]).astype(jnp.int32))
         idx = blk_ref[base + k] * block + within
-        pl.store(out_ref, (pl.ds(k, 1),),
-                 jnp.minimum(idx, n).astype(jnp.int32)[None])
+        out_ref[pl.ds(k, 1)] = jnp.minimum(idx, n).astype(jnp.int32)[None]
 
     access_execute([ring], chunk, execute)
 
 
 def searchsorted_blocks(tiles: jax.Array, blk: jax.Array, keys: jax.Array,
                         n: int, *, chunk: int, rif: int,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """tiles (NB, block) is the padded sorted table; blk (M,) int32 maps
     each key to the block holding its insertion point (ops.py's summary
     search); keys (M,) padded to a multiple of ``chunk``.  Returns (M,)
@@ -136,14 +135,13 @@ def _hash_probe_kernel(heads_ref, keys_ref, packed_hbm, out_ref, idx_s,
     def execute(k, ent):
         ek, ev, nxt = ent[0, 0], ent[0, 1], ent[0, 2]
         cur = idx_s[k]
-        found_k = pl.load(found_v, (pl.ds(0, 1), pl.ds(k, 1)))[0, 0]
+        found_k = found_v[pl.ds(0, 1), pl.ds(k, 1)][0, 0]
         alive = (cur >= 0) & (found_k == 0)
         hit = alive & (ek == keys_ref[base + k])
-        val_k = pl.load(val_v, (pl.ds(0, 1), pl.ds(k, 1)))[0, 0]
-        pl.store(val_v, (pl.ds(0, 1), pl.ds(k, 1)),
-                 jnp.where(hit, ev, val_k)[None, None])
-        pl.store(found_v, (pl.ds(0, 1), pl.ds(k, 1)),
-                 jnp.where(hit, 1, found_k)[None, None])
+        val_k = val_v[pl.ds(0, 1), pl.ds(k, 1)][0, 0]
+        val_v[pl.ds(0, 1), pl.ds(k, 1)] = jnp.where(hit, ev, val_k)[None, None]
+        found_v[pl.ds(0, 1), pl.ds(k, 1)] = jnp.where(hit, 1,
+                                                      found_k)[None, None]
         idx_s[k] = jnp.where(alive & ~hit, nxt, cur)
 
     def level(_, carry):
@@ -159,7 +157,7 @@ def _hash_probe_kernel(heads_ref, keys_ref, packed_hbm, out_ref, idx_s,
 
 def hash_probe(packed: jax.Array, heads: jax.Array, keys: jax.Array, *,
                chunk: int, rif: int, max_steps: int,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """packed (N, ENTRY_LANES) int32 rows [key, val, next, 0...]; heads /
     keys (M,) int32 padded to a multiple of ``chunk``.  Returns (M,)
     int32 lookup values (-1 when not found within ``max_steps``)."""

@@ -17,7 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import (cdiv, resolve_interpret, ring_rif,
+from repro.kernels.common import (note_knobs, resolve_interpret, ring_rif,
                                   round_up, tuned_knobs)
 from repro.kernels.dae_gather import kernel as _k
 from repro.kernels.dae_gather.ref import gather_ref
@@ -34,10 +34,12 @@ def _dae_gather_impl(table, idx, *, method, block_d, chunk, rif, interpret):
     if method == "ref":
         return gather_ref(table, idx)
 
-    # pad the feature dim to the lane granularity the kernels require
+    # pad rows to whole tiles and the feature dim to the lane
+    # granularity the kernels require (a no-op for aligned tables)
     dp = round_up(d, 128)
-    if dp != d:
-        table = jnp.pad(table, ((0, 0), (0, dp - d)))
+    np_ = round_up(n, _k.ROW_TILE)
+    if (np_, dp) != (n, d):
+        table = jnp.pad(table, ((0, np_ - n), (0, dp - d)))
 
     if method == "pipelined":
         bd = block_d or min(dp, 512)
@@ -88,5 +90,7 @@ def dae_gather(
             knobs["chunk"]
         dp = round_up(max(d, 1), 128)
         rif = ring_rif(knobs["rif"], chunk * dp * table.dtype.itemsize)
+    note_knobs("dae_gather", (n, d, idx.shape[0]), method=method,
+               block_d=block_d, chunk=chunk, rif=rif)
     return _dae_gather_impl(table, idx, method=method, block_d=block_d,
                             chunk=chunk, rif=rif, interpret=interp)

@@ -71,7 +71,7 @@ def _merge_kernel(sa_ref, sb_ref, a_hbm, b_hbm, out_ref, wa, sem_a, wb, sem_b,
 
 def merge_tiles(a_pad: jax.Array, b_pad: jax.Array, starts_a: jax.Array,
                 starts_b: jax.Array, n_out: int, *, tile: int, rif: int = 2,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """a_pad/b_pad are the runs padded with +inf sentinels so any
     (start, start+tile) window is in bounds; starts_* (n_tiles,) are the
     merge-path splits; output is n_out = n_tiles * tile elements.

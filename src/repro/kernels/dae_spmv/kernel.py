@@ -58,7 +58,7 @@ def _spmv_kernel(row_ref, col_ref, val_ref, vec_hbm, out_ref, vscr, vsem, *,
 
 def bsr_spmv(val_blocks: jax.Array, row_ids: jax.Array, col_ids: jax.Array,
              vec_tiles: jax.Array, nrows_blocks: int, *, rif: int = 2,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool) -> jax.Array:
     """val_blocks (NB, BM, BK); row_ids/col_ids (NB,) with row_ids sorted
     ascending and every row block present at least once (ops.py pads empty
     rows with zero blocks); vec_tiles (KB, BK) -> out (nrows_blocks, BM).

@@ -85,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 
 def flash(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
           window: Optional[int], scale: float, s_real: int, bq: int, bk: int,
-          interpret: bool = True) -> jax.Array:
+          interpret: bool) -> jax.Array:
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]
     g = h // kvh
@@ -174,7 +174,7 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, acc, m_s, l_s,
 
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  lengths: jax.Array, *, scale: float, bk: int, rif: int = 2,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """q (B, KVH, G, D); caches (B, KVH, S, D); lengths (B,) int32.
     ``rif`` K/V block pairs stream ahead of the MXU consume."""
     b, kvh, g, d = q.shape
@@ -233,7 +233,7 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref,
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        page_table: jax.Array, lengths: jax.Array, *,
                        scale: float, rif: int = 2,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """q (B, KVH, G, D); pages (NP, KVH, PAGE, D); page_table (B, S/PAGE).
 
     The page table is the decoupled request stream: the K/V rings consume

@@ -8,7 +8,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import (cdiv, resolve_interpret, ring_rif,
+from repro.kernels.common import (note_knobs, resolve_interpret, ring_rif,
                                   round_up, tuned_knobs)
 from repro.kernels.flash_attention import kernel as _k
 from repro.kernels.flash_attention.ref import attention_ref, decode_ref
@@ -49,6 +49,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                             (q.shape[2], k.shape[2], q.shape[3]), q.dtype,
                             interp, bq=(bq, 128), bk=(bk, 128))
         bq, bk = knobs["bq"], knobs["bk"]
+    note_knobs("flash_attention", (q.shape[2], k.shape[2], q.shape[3]),
+               bq=bq, bk=bk)
     return _flash_impl(q, k, v, causal=causal, window=window, bq=bq, bk=bk,
                        interpret=interp, method=method)
 
@@ -88,6 +90,8 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                             q.dtype, interp, bk=(bk, 128), rif=(rif, None))
         bk, rif = knobs["bk"], knobs["rif"]
         rif = ring_rif(rif, bk * q.shape[2] * q.dtype.itemsize)
+    note_knobs("flash_decode", (k_cache.shape[2], q.shape[2]), bk=bk,
+               rif=rif)
     return _decode_impl(q, k_cache, v_cache, lengths, bk=bk, rif=rif,
                         interpret=interp, method=method)
 
@@ -129,5 +133,7 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
                           rif=(None, None))["rif"]
         rif = ring_rif(rif, k_pages.shape[2] * q.shape[2]
                        * q.dtype.itemsize)
+    note_knobs("flash_decode_paged", (k_pages.shape[2], q.shape[2]),
+               rif=rif)
     return _decode_paged_impl(q, k_pages, v_pages, page_table, lengths,
                               rif=rif, interpret=interp, method=method)

@@ -66,7 +66,7 @@ def _gmm_kernel(be_ref, x_ref, w_hbm, o_ref, acc, wscr, wsem, *,
 
 
 def gmm(x: jax.Array, w: jax.Array, block_expert: jax.Array, *, bt: int,
-        bf: int, bd: int, rif: int, interpret: bool = True) -> jax.Array:
+        bf: int, bd: int, rif: int, interpret: bool) -> jax.Array:
     """x (T, D) sorted by expert, T % bt == 0; w (E, D, F);
     block_expert (T//bt,) int32.  Returns (T, F).  ``rif`` expert weight
     tiles stream ahead of the consuming grid step."""

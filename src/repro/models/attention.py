@@ -19,8 +19,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import (ModelConfig, dense_init, rmsnorm,
-                                 rmsnorm_init, rope)
+from repro.models.common import (ModelConfig, dense_init, on_each_device,
+                                 rmsnorm, rmsnorm_init, rope)
 from repro.kernels.flash_attention.ops import (flash_attention, flash_decode,
                                                flash_decode_paged)
 from repro.kernels.flash_attention.ref import (attention_banded,
@@ -141,8 +141,8 @@ def gqa_apply(cfg: ModelConfig, p, x, positions, *, causal: bool = True,
         lens = pos + valid.sum(-1).astype(pos.dtype)
         qlens = pos[:, None] + jnp.arange(1, s + 1, dtype=pos.dtype)[None]
         if s == 1 and cfg.kernel_mode == "pallas":
-            out = flash_decode_paged(q[:, :, 0, :], kp, vp, page_table,
-                                     qlens[:, 0])
+            out = on_each_device(cfg, flash_decode_paged, q[:, :, 0, :],
+                                 kp, vp, page_table, qlens[:, 0])
             out = out[:, :, None, :]
         else:
             kc = _gather_pages(kp, page_table)
@@ -452,8 +452,9 @@ def mla_apply(cfg: ModelConfig, p, x, positions, *, causal: bool = True,
     elif chunked:
         qlens = pos[:, None] + jnp.arange(1, s + 1, dtype=pos.dtype)[None]
         if s == 1 and cfg.kernel_mode == "pallas":
-            out = flash_decode(qk[:, :, 0, :], k, v_pad_to(v, k.shape[-1]),
-                               qlens[:, 0])[..., :dv][:, :, None, :]
+            out = on_each_device(cfg, flash_decode, qk[:, :, 0, :], k,
+                                 v_pad_to(v, k.shape[-1]), qlens[:, 0])
+            out = out[..., :dv][:, :, None, :]
         else:
             out = decode_chunk_ref(qk, k, v_pad_to(v, k.shape[-1]),
                                    qlens)[..., :dv]            # (B,H,S,dv)

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -157,6 +157,22 @@ class ModelConfig:
             segs.append(LayerSpec("moe", self.n_layers - self.first_dense_layers))
             return segs
         return [LayerSpec("attn", self.n_layers)]
+
+
+def on_each_device(cfg: ModelConfig, kernel: Callable, *args):
+    """Call a Pallas ``kernel`` where the model may run on a mesh.
+
+    XLA cannot partition a Mosaic call.  When the model runs under a
+    serving mesh (``cfg.mesh_pool_axis`` is set by the sharded serve
+    loop, which enters the mesh with ``jax.set_mesh``), the call goes
+    through ``shard_map`` with every operand replicated: each device runs
+    the kernel whole and the result comes out replicated.  Off a mesh
+    the kernel is called as is."""
+    if cfg.mesh_pool_axis is None:
+        return kernel(*args)
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(kernel, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------

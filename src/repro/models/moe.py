@@ -26,7 +26,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ModelConfig, dense_init
+from repro.models.common import ModelConfig, dense_init, on_each_device
 from repro.models.mlp import mlp_init, mlp_apply
 from repro.kernels.grouped_matmul.ops import grouped_matmul
 from repro.kernels.common import round_up
@@ -71,7 +71,9 @@ def moe_apply(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray,
     gates, experts = _route(cfg, p, x2d)
 
     if cfg.kernel_mode == "pallas":
-        y2d = _dispatch_pallas(cfg, p, x2d, gates, experts)
+        y2d = on_each_device(
+            cfg, lambda *a: _dispatch_pallas(cfg, *a), p, x2d, gates,
+            experts)
     else:
         y2d = _dispatch_xla(cfg, p, x2d, gates, experts,
                             capacity_factor or cfg.capacity_factor)

@@ -13,7 +13,7 @@ from repro.kernels.dae_gather.ops import dae_gather
 from repro.models.blocks import (block_apply, block_cache_init,
                                  block_cache_init_paged, block_init)
 from repro.models.common import (ModelConfig, cross_entropy_loss, dense_init,
-                                 rmsnorm, rmsnorm_init)
+                                 on_each_device, rmsnorm, rmsnorm_init)
 
 Params = Dict[str, Any]
 
@@ -54,7 +54,8 @@ def embed_tokens(cfg: ModelConfig, params: Params, tokens: jnp.ndarray
     """Vocab-table gather — the framework's dae_gather hook."""
     b, s = tokens.shape
     if cfg.kernel_mode == "pallas":
-        flat = dae_gather(params["embed"], tokens.reshape(-1).astype(jnp.int32))
+        flat = on_each_device(cfg, dae_gather, params["embed"],
+                              tokens.reshape(-1).astype(jnp.int32))
         return flat.reshape(b, s, cfg.d_model).astype(cfg.adtype)
     return jnp.take(params["embed"], tokens, axis=0).astype(cfg.adtype)
 
@@ -297,7 +298,18 @@ def lm_prefill(cfg: ModelConfig, params: Params, caches: List[Any],
                 ncs.append(nci)
             nc = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
         else:
-            x, nc = jax.lax.scan(body, x, (stacked, cache))
+            # the stacked cache rides the scan carry and each layer's
+            # slice is written back in place: as scan outputs, the new
+            # cache would be a second buffer the size of the whole pool
+            def layer(carry, layer_params, body=body):
+                h, cache_all, i = carry
+                h, nci = body(h, (layer_params,
+                                  jax.tree.map(lambda a: a[i], cache_all)))
+                cache_all = jax.tree.map(lambda a, n: a.at[i].set(n),
+                                         cache_all, nci)
+                return (h, cache_all, i + 1), None
+
+            (x, nc, _), _ = jax.lax.scan(layer, (x, cache, 0), stacked)
         new_caches.append(nc)
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)

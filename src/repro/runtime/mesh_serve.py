@@ -38,6 +38,7 @@ drive one dense cache and only the control channels are mesh-placed.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional
 
 import jax
@@ -104,34 +105,44 @@ class ShardedPagedServeLoop(PagedServeLoop):
                                           self.meshes.axis, src=span - 1,
                                           dst=0, tracer=self.tracer)
 
+    def _pool_on(self, mesh, n_pages: int):
+        """A zeroed page pool built directly in its sharded placement on
+        ``mesh``, so no device ever holds the whole pool on the way."""
+        init = functools.partial(self.bundle.cache_init_paged, self.b,
+                                 n_pages, self.page)
+        shardings = cache_shardings(jax.eval_shape(init), mesh, self.rules)
+        return jax.jit(init, out_shardings=shardings)()
+
+    def _new_pool(self, n_pages: int):
+        if self._place:
+            return self._pool_on(self.meshes.decode, n_pages)
+        return super()._new_pool(n_pages)
+
     def _make_cache(self) -> None:
         super()._make_cache()
         if not self.paged:
             return
-        dm = self.meshes.decode
         if self._place:
+            dm = self.meshes.decode
             self.params = jax.device_put(
                 self.params, param_shardings(self.params, dm, self.rules))
-            self.cache = jax.device_put(
-                self.cache, cache_shardings(self.cache, dm, self.rules))
             self._table_sh = page_table_sharding(dm, self.b, self.rules)
         if self._disagg:
             pm = self.meshes.prefill
             self._params_pf = jax.device_put(
                 self.params, param_shardings(self.params, pm, self.rules))
             # staging pool: every slot holds at most npb pages, so
-            # 1 + b*npb (trash page + b horizons) can never run dry
-            self.n_pages_pf = 1 + self.b * self.npb
+            # 1 + b*npb (trash page + b horizons) can never run dry;
+            # rounded up so the page dim divides over the prefill mesh
+            span = int(np.prod(list(pm.shape.values())))
+            self.n_pages_pf = -(-(1 + self.b * self.npb) // span) * span
             self.alloc_pf = PageAllocator(self.n_pages_pf, self.page)
             self.table_pf = np.zeros((self.b, self.npb), np.int32)
             self.n_blocks_pf = np.zeros(self.b, np.int64)
-            self.cache_pf = self.bundle.cache_init_paged(
-                self.b, self.n_pages_pf, self.page)
-            self.cache_pf = jax.device_put(
-                self.cache_pf,
-                cache_shardings(self.cache_pf, pm, self.rules))
+            self.cache_pf = self._pool_on(pm, self.n_pages_pf)
             self._gather = _shared_jit(self.bundle.gather_pages)
-            self._scatter = _shared_jit(self.bundle.scatter_pages)
+            self._scatter = _shared_jit(self.bundle.scatter_pages,
+                                        donate=(0,))
 
     # -- engine routing ------------------------------------------------------
 
@@ -151,7 +162,7 @@ class ShardedPagedServeLoop(PagedServeLoop):
             self.cache = self.cache_pf
             self.table = self.table_pf
             try:
-                with self.meshes.prefill:
+                with jax.set_mesh(self.meshes.prefill):
                     return super()._step(tok, n_valid)
             finally:
                 self.cache_pf = self.cache
@@ -160,7 +171,7 @@ class ShardedPagedServeLoop(PagedServeLoop):
         if self._place:
             self.table = jax.device_put(np.asarray(tbl), self._table_sh)
         try:
-            with self.meshes.decode:
+            with jax.set_mesh(self.meshes.decode):
                 return super()._step(tok, n_valid)
         finally:
             self.table = tbl
@@ -221,10 +232,10 @@ class ShardedPagedServeLoop(PagedServeLoop):
         pad = self.npb - len(src)
         src_a = jnp.asarray(src + [0] * pad, jnp.int32)
         dst_a = jnp.asarray(dst + [0] * pad, jnp.int32)
-        with self.meshes.prefill:
+        with jax.set_mesh(self.meshes.prefill):
             blocks = self._gather(self.cache_pf, src_a)
         blocks = jax.device_get(blocks)          # prefill -> decode hop
-        with self.meshes.decode:
+        with jax.set_mesh(self.meshes.decode):
             self.cache = self._scatter(self.cache, blocks, dst_a,
                                        np.int32(slot), np.int32(new_len))
         self.stats.migrations += 1

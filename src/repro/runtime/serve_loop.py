@@ -76,14 +76,18 @@ from repro.core.trace import Tracer
 _FREE, _PREFILL, _HANDOFF, _DECODE = 0, 1, 2, 3
 
 
-def _shared_jit(fn):
+def _shared_jit(fn, donate: Tuple[int, ...] = ()):
     """One jit wrapper (and hence one compile cache) per bundle
     function, shared across every loop instance built on that bundle —
     constructing a fresh ServeLoop costs no recompilation.  The wrapper
-    is stashed on the function itself so it dies with the bundle."""
+    is stashed on the function itself so it dies with the bundle.
+
+    ``donate`` names the cache argument of a function that returns the
+    new cache: the step then updates the KV pool in place instead of
+    holding the old and the new pool at once."""
     jitted = getattr(fn, "_serve_jit", None)
     if jitted is None:
-        jitted = jax.jit(fn)
+        jitted = jax.jit(fn, donate_argnums=donate)
         fn._serve_jit = jitted
     return jitted
 
@@ -278,12 +282,16 @@ class ServeLoop:
     records channel occupancy; ``stats`` counts steps/tokens and TTFT.
     Encoder-decoder bundles are served too: requests carry ``frames``,
     encoded once at admission into a per-slot encoder-output buffer.
+    ``record_logits`` keeps, per request id, the float32 logits row each
+    emitted token was chosen from (``token_logits``), so a run can be
+    compared with another kernel mode or a reference.
     """
 
     def __init__(self, cfg, bundle, params, batch_slots: int, s_max: int,
                  eos_id: int = -1, chunk: int = 32, bos_id: int = 0,
                  tracer: Optional[Tracer] = None,
-                 admit_capacity: Optional[int] = None):
+                 admit_capacity: Optional[int] = None,
+                 record_logits: bool = False):
         if chunk < 1:
             raise ValueError("chunk must be >= 1")
         self.cfg = cfg
@@ -303,6 +311,8 @@ class ServeLoop:
         self._ptr = np.zeros(batch_slots, np.int64)     # prefill progress
         self._psize = np.zeros(batch_slots, np.int64)   # original prompt size
         self._prompt: List[Optional[np.ndarray]] = [None] * batch_slots
+        self.token_logits: Optional[Dict[int, List[np.ndarray]]] = \
+            {} if record_logits else None
 
         self.paged = False
         self._make_cache()
@@ -330,8 +340,10 @@ class ServeLoop:
     def _make_cache(self) -> None:
         """Cache + compiled-primitive setup; PagedServeLoop overrides."""
         self.cache = self.bundle.cache_init(self.b, self.s_max)
-        self._fwd = _shared_jit(self.bundle.prefill)
-        self._reset = _shared_jit(self.bundle.cache_reset)
+        # encdec prefill takes (params, enc_out, cache, ...)
+        cache_arg = 2 if self.cfg.family == "encdec" else 1
+        self._fwd = _shared_jit(self.bundle.prefill, donate=(cache_arg,))
+        self._reset = _shared_jit(self.bundle.cache_reset, donate=(0,))
 
     # -- shared step dispatch ------------------------------------------------
 
@@ -405,10 +417,15 @@ class ServeLoop:
     def _on_prompt_complete(self, slot: int) -> None:
         pass
 
+    def _keep_logits(self, rid: int, row: np.ndarray) -> None:
+        if self.token_logits is not None:
+            self.token_logits.setdefault(rid, []).append(np.array(row))
+
     def _first_token(self, slot: int, logits: np.ndarray) -> int:
         req = self.active[slot]
         first = int(np.argmax(logits[slot]))
         req.out.append(first)
+        self._keep_logits(req.rid, logits[slot])
         return first
 
     def _prefill_step(self, t0: float, results: Dict[int, List[int]]) -> None:
@@ -483,6 +500,7 @@ class ServeLoop:
             tok = int(nxt[slot])
             req = self.active[slot]
             req.out.append(tok)
+            self._keep_logits(req.rid, logits[slot])
             self.cur[slot] = tok
             self.pos[slot] += 1
             self.remaining[slot] -= 1
@@ -566,14 +584,16 @@ class PagedServeLoop(ServeLoop):
                  tracer: Optional[Tracer] = None,
                  admit_capacity: Optional[int] = None,
                  page: int = 16, n_pages: Optional[int] = None,
-                 low_water: int = 0, prefix_reuse: bool = True):
+                 low_water: int = 0, prefix_reuse: bool = True,
+                 record_logits: bool = False):
         self.page = page
         self._n_pages_arg = n_pages
         self.low_water = low_water
         self._prefix_reuse = prefix_reuse
         super().__init__(cfg, bundle, params, batch_slots, s_max,
                          eos_id=eos_id, chunk=chunk, bos_id=bos_id,
-                         tracer=tracer, admit_capacity=admit_capacity)
+                         tracer=tracer, admit_capacity=admit_capacity,
+                         record_logits=record_logits)
 
     def _make_cache(self) -> None:
         bundle = self.bundle
@@ -600,10 +620,16 @@ class PagedServeLoop(ServeLoop):
         self._seq = 0
         self._resume_out: Dict[int, List[int]] = {}
         self._is_resume = np.zeros(self.b, bool)
-        self.cache = bundle.cache_init_paged(self.b, n_pages, self.page)
-        self._fwd = _shared_jit(bundle.prefill_paged)
-        self._reset_paged = _shared_jit(bundle.cache_reset_paged)
-        self._copy = _shared_jit(bundle.copy_pages)
+        self.cache = self._new_pool(n_pages)
+        self._fwd = _shared_jit(bundle.prefill_paged, donate=(1,))
+        self._reset_paged = _shared_jit(bundle.cache_reset_paged,
+                                        donate=(0,))
+        self._copy = _shared_jit(bundle.copy_pages, donate=(0,))
+
+    def _new_pool(self, n_pages: int):
+        """A zeroed ``n_pages`` page pool; the sharded loop builds it in
+        place on its decode mesh."""
+        return self.bundle.cache_init_paged(self.b, n_pages, self.page)
 
     # -- page machinery ------------------------------------------------------
 

@@ -225,6 +225,12 @@ def test_build_report_validates_and_rounds():
     row = rep["cells"][0]
     assert (row["us_cold"], row["us_warm"]) == (1.2, 1.0)
     assert rep["meta"]["seed"] == 7
+    # the device is recorded as JAX reports it, never left unattributed
+    import jax
+    dev = jax.devices()[0]
+    assert (rep["meta"]["backend"], rep["meta"]["device_kind"],
+            rep["meta"]["device_count"]) == (dev.platform, dev.device_kind,
+                                             len(jax.devices()))
     with pytest.raises(SchemaError):
         build_report("sim", [(cell, CellResult(us_cold=1.0))],
                      smoke=True, seed=0)
@@ -293,3 +299,12 @@ def test_matrix_cells_match_committed_baseline_names():
             f"axis {axis}: declared-vs-baseline cell mismatch "
             f"(+{sorted(declared - committed)} "
             f"-{sorted(committed - declared)})")
+
+
+def test_compile_cache_dir_env_then_fixed_checkout_path(monkeypatch,
+                                                       tmp_path):
+    from repro.launch.compile_cache import ENV_VAR, compile_cache_dir
+    monkeypatch.setenv(ENV_VAR, str(tmp_path))
+    assert compile_cache_dir() == tmp_path
+    monkeypatch.delenv(ENV_VAR)
+    assert compile_cache_dir() == REPO_ROOT / ".jax_cache"

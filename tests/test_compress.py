@@ -15,10 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from repro.parallel.compress import (compressed_grad_mean, compressed_psum,
                                      dequantize, quantize)
@@ -104,10 +101,7 @@ def test_compressed_allreduce_8_devices():
     snippet = """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        try:
-            from jax import shard_map as _shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as _shard_map
+        from jax import shard_map as _shard_map
         from repro.parallel.compress import compressed_psum
 
         assert jax.device_count() == 8

@@ -29,14 +29,14 @@ def _np(x):
 # ---------------------------------------------------------------------------
 
 
-def check_gather(case, seed=0):
+def check_gather(case, seed=0, method="rif"):
     from repro.kernels.dae_gather import dae_gather, gather_ref
     r = np.random.default_rng(seed)
     dtype = jnp.dtype(case["dtype"])
     table = jnp.asarray(r.standard_normal((case["n"], case["d"])), dtype)
     idx = jnp.asarray(r.integers(0, case["n"], case["m"]), jnp.int32)
-    out = dae_gather(table, idx, method="rif", chunk=case["chunk"],
-                     rif=case["rif"], interpret=True)
+    out = dae_gather(table, idx, method=method, block_d=128,
+                     chunk=case["chunk"], rif=case["rif"], interpret=True)
     np.testing.assert_array_equal(_np(out), _np(gather_ref(table, idx)))
 
 
@@ -172,6 +172,8 @@ GATHER_EDGES = [
     dict(n=40, d=128, m=17, chunk=8, rif=64, dtype="float32"),  # rif>chunk
     dict(n=7, d=130, m=5, chunk=64, rif=4, dtype="bfloat16"),   # tails
     dict(n=1, d=8, m=1, chunk=1, rif=1, dtype="float32"),       # singleton
+    dict(n=13, d=128, m=9, chunk=4, rif=2, dtype="int32"),      # int rows
+    dict(n=13, d=128, m=9, chunk=4, rif=2, dtype="int8"),       # narrow int
 ]
 
 MERGE_EDGES = [
@@ -225,9 +227,10 @@ GMM_EDGES = [
 ]
 
 
+@pytest.mark.parametrize("method", ["rif", "pipelined"])
 @pytest.mark.parametrize("case", GATHER_EDGES)
-def test_gather_edges(case):
-    check_gather(case)
+def test_gather_edges(case, method):
+    check_gather(case, method=method)
 
 
 @pytest.mark.parametrize("case", MERGE_EDGES)

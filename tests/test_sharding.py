@@ -13,7 +13,7 @@ from repro.parallel.sharding import (ShardingRules, _divisible,
                                      cache_shardings, page_table_sharding,
                                      param_shardings)
 
-MESH8 = AbstractMesh((("data", 8),))
+MESH8 = AbstractMesh((8,), ("data",))
 
 
 def _sds(shape, dtype=jnp.bfloat16):
@@ -94,7 +94,7 @@ def test_page_table_sharding():
 def test_param_shardings_drop_indivisible_dims():
     # wq (D=96, H*hd=100): 100 % 8 != 0 on the model axis -> that dim
     # replicates; fsdp dim 96 % 8 == 0 -> data
-    mesh = AbstractMesh((("data", 8), ("model", 8)))
+    mesh = AbstractMesh((8, 8), ("data", "model"))
     params = {"layers": {"wq": _sds((96, 100))}}
     out = param_shardings(params, mesh)
     assert out["layers"]["wq"].spec == P("data", None)
