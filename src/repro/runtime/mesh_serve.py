@@ -45,14 +45,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.channels import LocalChannel, MeshChannel
+from repro.channels import MeshChannel
 from repro.core.trace import Tracer
 from repro.launch.mesh import ServeMeshes, make_serve_meshes
 from repro.models.registry import build_model
 from repro.parallel.sharding import (ShardingRules, cache_shardings,
                                      page_table_sharding, param_shardings)
 from repro.runtime.serve_loop import (PageAllocator, PagedServeLoop,
-                                      _shared_jit)
+                                      _AdmitChannel, _shared_jit)
 
 __all__ = ["ShardedPagedServeLoop"]
 
@@ -87,8 +87,8 @@ class ShardedPagedServeLoop(PagedServeLoop):
     # -- placement -----------------------------------------------------------
 
     def _make_channels(self) -> None:
-        self.admit_q = LocalChannel("admit", self._admit_capacity,
-                                    self.tracer)
+        self.admit_q = _AdmitChannel("admit", self._admit_capacity,
+                                     self.tracer)
         if self._disagg:
             um, ax = self.meshes.union, self.meshes.role_axis
             self.handoff = MeshChannel("prefill_done", self.b, um, ax,

@@ -59,6 +59,7 @@ contiguous path, bit-parity-pinned by the serve tests.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import time
@@ -100,6 +101,7 @@ class Request:
     out: Optional[List[int]] = None
     frames: Optional[np.ndarray] = None   # encdec: (S_enc, D) frontend frames
     t_arrival: float = 0.0      # seconds after run() start (open-loop traces)
+    t_queued: float = 0.0       # perf_counter() when it last entered admit_q
 
 
 def _validate_requests(requests: List[Request], s_max: int,
@@ -129,6 +131,33 @@ def _validate_requests(requests: List[Request], s_max: int,
 Channel = LocalChannel
 
 
+class _AdmitChannel(LocalChannel):
+    """The admit queue.  Stamps each request it takes with the time, so
+    that admission can count how long the request waited for a slot,
+    whoever pushed it (``run``, a preemption, a subclass's client)."""
+
+    __slots__ = ()
+
+    def push(self, item: Request) -> bool:
+        if not super().push(item):
+            return False
+        item.t_queued = time.perf_counter()
+        return True
+
+
+@contextlib.contextmanager
+def _span(stats: "ServeStats", name: str, counter: str):
+    """A host span: ``name`` on the profiler's trace (where one is being
+    recorded) and its seconds added to ``stats.<counter>``."""
+    t = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        setattr(stats, counter,
+                getattr(stats, counter) + time.perf_counter() - t)
+
+
 @dataclasses.dataclass
 class ServeStats:
     """Counters the serve bench reports; ttft is wall-clock seconds from
@@ -155,6 +184,25 @@ class ServeStats:
     # active slots — what a reservation-based contiguous allocator
     # would have had to set aside (the oversubscription witness)
     peak_reserved_tokens: int = 0
+    # host seconds by phase of a round, each also a profiler span:
+    # serve.admit, serve.prepare (inputs of a step), serve.sample (the
+    # decode argmax), serve.emit (outputs of a step); none holds a
+    # _step call.  Inside _step: step.dispatch (inputs to the device and
+    # the call), step.wait (until the logits are ready), step.copy (the
+    # logits to the host, logits_bytes of them).  Flat fields, so that
+    # a shallow copy of the stats is a snapshot.
+    admit_s: float = 0.0
+    prepare_s: float = 0.0
+    sample_s: float = 0.0
+    emit_s: float = 0.0
+    dispatch_s: float = 0.0
+    wait_s: float = 0.0
+    copy_s: float = 0.0
+    logits_bytes: int = 0
+    # seconds requests spent in admit_q before a slot took them, and how
+    # many were taken (a preempted request counts again)
+    queue_wait_s: float = 0.0
+    queue_waits: int = 0
 
 
 class PageAllocator:
@@ -279,7 +327,9 @@ class ServeLoop:
     ``chunk`` is the Access engine's tokens-per-step (the decoupling
     knob: larger chunks amortize dispatch, smaller chunks bound the
     decode stream's stall).  ``tracer`` (a ``repro.core.trace.Tracer``)
-    records channel occupancy; ``stats`` counts steps/tokens and TTFT.
+    records channel occupancy; ``stats`` counts steps/tokens, TTFT, the
+    admit queue's waits and the host seconds of each phase of a round,
+    each phase also a ``jax.profiler`` span (see :class:`ServeStats`).
     Encoder-decoder bundles are served too: requests carry ``frames``,
     encoded once at admission into a per-slot encoder-output buffer.
     ``record_logits`` keeps, per request id, the float32 logits row each
@@ -333,7 +383,8 @@ class ServeLoop:
     def _make_channels(self) -> None:
         """Engine-joining channels; the sharded loop overrides to place
         handoff/free_slots on a mesh transport."""
-        self.admit_q = Channel("admit", self._admit_capacity, self.tracer)
+        self.admit_q = _AdmitChannel("admit", self._admit_capacity,
+                                     self.tracer)
         self.handoff = Channel("prefill_done", self.b, self.tracer)
         self.free_slots = Channel("free_slots", self.b, self.tracer)
 
@@ -348,44 +399,60 @@ class ServeLoop:
     # -- shared step dispatch ------------------------------------------------
 
     def _step(self, tok: np.ndarray, n_valid: np.ndarray):
-        args = (jnp.asarray(tok, jnp.int32), jnp.asarray(self.pos),
-                jnp.asarray(n_valid, jnp.int32))
-        if self.paged:
-            args = args + (jnp.asarray(self.table),)
-            logits, self.cache = self._fwd(self.params, self.cache, *args)
-        elif self._encdec:
-            logits, self.cache = self._fwd(self.params, self.enc_out,
-                                           self.cache, *args)
-        else:
-            logits, self.cache = self._fwd(self.params, self.cache, *args)
-        return np.asarray(logits)
+        st = self.stats
+        with _span(st, "step.dispatch", "dispatch_s"):
+            args = (jnp.asarray(tok, jnp.int32), jnp.asarray(self.pos),
+                    jnp.asarray(n_valid, jnp.int32))
+            if self.paged:
+                args = args + (jnp.asarray(self.table),)
+                logits, self.cache = self._fwd(self.params, self.cache,
+                                               *args)
+            elif self._encdec:
+                logits, self.cache = self._fwd(self.params, self.enc_out,
+                                               self.cache, *args)
+            else:
+                logits, self.cache = self._fwd(self.params, self.cache,
+                                               *args)
+        with _span(st, "step.wait", "wait_s"):
+            jax.block_until_ready(logits)
+        with _span(st, "step.copy", "copy_s"):
+            out = np.asarray(logits)
+        st.logits_bytes += out.nbytes
+        return out
+
+    def _dequeued(self, req: Request) -> None:
+        """Count the wait of a request a slot took from admit_q."""
+        self.stats.queue_wait_s += time.perf_counter() - req.t_queued
+        self.stats.queue_waits += 1
 
     # -- Access engine: admission + chunked prefill --------------------------
 
     def _admit(self) -> None:
-        reset: List[int] = []
-        while self.free_slots and self.admit_q:
-            slot = self.free_slots.pop()
-            req = self.admit_q.pop()
-            prompt = np.asarray(req.prompt, np.int64).reshape(-1)
-            if prompt.size == 0:
-                # empty prompt: generate from an implicit BOS token
-                prompt = np.array([self.bos], np.int64)
-            req.out = []
-            self.active[slot] = req
-            self._prompt[slot] = prompt
-            self._psize[slot] = prompt.size
-            self._ptr[slot] = 0
-            self.pos[slot] = 0
-            self.phase[slot] = _PREFILL
-            self.stats.admitted += 1
-            reset.append(slot)
-        if reset:
-            keep = np.ones(self.b, bool)
-            keep[reset] = False
-            self.cache = self._reset(self.cache, jnp.asarray(keep))
-            if self._encdec:
-                self._encode_slots(reset)
+        with _span(self.stats, "serve.admit", "admit_s"):
+            reset: List[int] = []
+            while self.free_slots and self.admit_q:
+                slot = self.free_slots.pop()
+                req = self.admit_q.pop()
+                self._dequeued(req)
+                prompt = np.asarray(req.prompt, np.int64).reshape(-1)
+                if prompt.size == 0:
+                    # empty prompt: generate from an implicit BOS token
+                    prompt = np.array([self.bos], np.int64)
+                req.out = []
+                self.active[slot] = req
+                self._prompt[slot] = prompt
+                self._psize[slot] = prompt.size
+                self._ptr[slot] = 0
+                self.pos[slot] = 0
+                self.phase[slot] = _PREFILL
+                self.stats.admitted += 1
+                reset.append(slot)
+            if reset:
+                keep = np.ones(self.b, bool)
+                keep[reset] = False
+                self.cache = self._reset(self.cache, jnp.asarray(keep))
+                if self._encdec:
+                    self._encode_slots(reset)
 
     def _encode_slots(self, slots: List[int]) -> None:
         for slot in slots:
@@ -429,53 +496,56 @@ class ServeLoop:
         return first
 
     def _prefill_step(self, t0: float, results: Dict[int, List[int]]) -> None:
-        slots = np.flatnonzero(self.phase == _PREFILL)
-        if slots.size == 0:
-            return
-        tok = np.zeros((self.b, self.chunk), np.int64)
-        n_valid = np.zeros(self.b, np.int64)
-        for slot in slots:
-            if self.phase[slot] != _PREFILL:    # preempted by an earlier grant
-                continue
-            prompt = self._prompt[slot]
-            n = min(self.chunk, prompt.size - self._ptr[slot])
-            n = self._prefill_grant(slot, int(self._ptr[slot]), int(n))
-            if n > 0:
-                tok[slot, :n] = prompt[self._ptr[slot]:self._ptr[slot] + n]
-            n_valid[slot] = n
-        n_valid[self.phase != _PREFILL] = 0
-        if not n_valid.any():
-            return                              # everyone stalled on pages
+        st = self.stats
+        with _span(st, "serve.prepare", "prepare_s"):
+            slots = np.flatnonzero(self.phase == _PREFILL)
+            if slots.size == 0:
+                return
+            tok = np.zeros((self.b, self.chunk), np.int64)
+            n_valid = np.zeros(self.b, np.int64)
+            for slot in slots:
+                if self.phase[slot] != _PREFILL:  # preempted by a grant
+                    continue
+                prompt = self._prompt[slot]
+                n = min(self.chunk, prompt.size - self._ptr[slot])
+                n = self._prefill_grant(slot, int(self._ptr[slot]), int(n))
+                if n > 0:
+                    tok[slot, :n] = prompt[self._ptr[slot]:self._ptr[slot] + n]
+                n_valid[slot] = n
+            n_valid[self.phase != _PREFILL] = 0
+            if not n_valid.any():
+                return                          # everyone stalled on pages
         logits = self._step(tok, n_valid)
-        self.stats.prefill_steps += 1
-        self.stats.prefill_tokens += int(n_valid.sum())
-        for slot in slots:
-            if self.phase[slot] != _PREFILL:
-                continue
-            self._ptr[slot] += n_valid[slot]
-            self.pos[slot] += n_valid[slot]
-            if self._ptr[slot] < self._prompt[slot].size:
-                continue
-            # prompt complete: the chunk's last-valid logits are the
-            # prediction after the final prompt token — the first output
-            # token rides the handoff channel into the Execute engine,
-            # which activates the slot when it pops the entry
-            req = self.active[slot]
-            self._on_prompt_complete(slot)
-            if self.active[slot] is not req:
-                # the hook preempted/parked the slot (e.g. the sharded
-                # loop's prefill->decode page migration ran dry)
-                continue
-            first = self._first_token(slot, logits)
-            if req.rid not in self.stats.ttft:   # resumes keep the original
-                self.stats.ttft[req.rid] = (time.perf_counter() - t0
-                                            - req.t_arrival)
-            self.remaining[slot] = req.max_new - len(req.out)
-            if first == self.eos or self.remaining[slot] <= 0:
-                self._finish(slot, results)
-            else:
-                self.phase[slot] = _HANDOFF
-                self.handoff.push((slot, first))
+        with _span(st, "serve.emit", "emit_s"):
+            st.prefill_steps += 1
+            st.prefill_tokens += int(n_valid.sum())
+            for slot in slots:
+                if self.phase[slot] != _PREFILL:
+                    continue
+                self._ptr[slot] += n_valid[slot]
+                self.pos[slot] += n_valid[slot]
+                if self._ptr[slot] < self._prompt[slot].size:
+                    continue
+                # prompt complete: the chunk's last-valid logits are the
+                # prediction after the final prompt token — the first
+                # output token rides the handoff channel into the Execute
+                # engine, which activates the slot when it pops the entry
+                req = self.active[slot]
+                self._on_prompt_complete(slot)
+                if self.active[slot] is not req:
+                    # the hook preempted/parked the slot (e.g. the sharded
+                    # loop's prefill->decode page migration ran dry)
+                    continue
+                first = self._first_token(slot, logits)
+                if req.rid not in st.ttft:      # resumes keep the original
+                    st.ttft[req.rid] = (time.perf_counter() - t0
+                                        - req.t_arrival)
+                self.remaining[slot] = req.max_new - len(req.out)
+                if first == self.eos or self.remaining[slot] <= 0:
+                    self._finish(slot, results)
+                else:
+                    self.phase[slot] = _HANDOFF
+                    self.handoff.push((slot, first))
 
     # -- Execute engine: dense masked decode ---------------------------------
 
@@ -483,29 +553,34 @@ class ServeLoop:
         return self.phase == _DECODE
 
     def _decode_step(self, results: Dict[int, List[int]]) -> None:
-        # absorb freshly prefilled slots: the (slot, first token) entry
-        # on the handoff channel is what activates decoding
-        while self.handoff:
-            slot, first = self.handoff.pop()
-            self.cur[slot] = first
-            self.phase[slot] = _DECODE
-        active = self._decode_mask()
-        if not active.any():
-            return
-        logits = self._step(self.cur[:, None], active.astype(np.int64))
-        nxt = np.argmax(logits, axis=-1)
-        self.stats.decode_steps += 1
-        self.stats.decode_tokens += int(active.sum())
-        for slot in np.flatnonzero(active):
-            tok = int(nxt[slot])
-            req = self.active[slot]
-            req.out.append(tok)
-            self._keep_logits(req.rid, logits[slot])
-            self.cur[slot] = tok
-            self.pos[slot] += 1
-            self.remaining[slot] -= 1
-            if tok == self.eos or self.remaining[slot] <= 0:
-                self._finish(slot, results)
+        st = self.stats
+        with _span(st, "serve.prepare", "prepare_s"):
+            # absorb freshly prefilled slots: the (slot, first token)
+            # entry on the handoff channel is what activates decoding
+            while self.handoff:
+                slot, first = self.handoff.pop()
+                self.cur[slot] = first
+                self.phase[slot] = _DECODE
+            active = self._decode_mask()
+            if not active.any():
+                return
+            tok, n_valid = self.cur[:, None], active.astype(np.int64)
+        logits = self._step(tok, n_valid)
+        with _span(st, "serve.sample", "sample_s"):
+            nxt = np.argmax(logits, axis=-1)
+        with _span(st, "serve.emit", "emit_s"):
+            st.decode_steps += 1
+            st.decode_tokens += int(active.sum())
+            for slot in np.flatnonzero(active):
+                tok = int(nxt[slot])
+                req = self.active[slot]
+                req.out.append(tok)
+                self._keep_logits(req.rid, logits[slot])
+                self.cur[slot] = tok
+                self.pos[slot] += 1
+                self.remaining[slot] -= 1
+                if tok == self.eos or self.remaining[slot] <= 0:
+                    self._finish(slot, results)
 
     def _finish(self, slot: int, results: Dict[int, List[int]]) -> None:
         req = self.active[slot]
@@ -542,6 +617,7 @@ class ServeLoop:
         while (pending or self._overflow or self.admit_q
                or (self.phase != _FREE).any()):
             rounds += 1
+            self.stats.rounds = rounds
             if rounds > max_rounds:
                 raise RuntimeError("serve loop exceeded max_rounds")
             # preempted/backlogged requests re-enter ahead of new arrivals
@@ -562,7 +638,6 @@ class ServeLoop:
                 wait = pending[0].t_arrival - (time.perf_counter() - t0)
                 if wait > 0:                 # open-loop idle: sleep to arrival
                     time.sleep(min(wait, 0.05))
-        self.stats.rounds = rounds
         return results
 
 
@@ -705,65 +780,66 @@ class PagedServeLoop(ServeLoop):
     def _admit(self) -> None:
         if not self.paged:
             return super()._admit()
-        reset: List[int] = []
-        new_lens = np.zeros(self.b, np.int64)
-        while self.free_slots and self.admit_q:
-            req = self.admit_q.peek()
-            prompt = np.asarray(req.prompt, np.int64).reshape(-1)
-            if prompt.size == 0:
-                prompt = np.array([self.bos], np.int64)
-            resume = self._resume_out.get(req.rid)
-            if resume:
-                # teacher-force the tokens generated before preemption;
-                # the last one re-enters decode via the handoff channel
-                fill = np.concatenate(
-                    [prompt, np.asarray(resume[:-1], np.int64)])
-            else:
-                fill = prompt
-            matched, pages = 0, []
-            if self.prefix is not None:
-                # at least one token must actually prefill (its logits
-                # seed the first output), hence the size-1 cap
-                matched, pages = self.prefix.lookup(
-                    fill, fill.size - 1, self.alloc)
-            total_blocks = -(-fill.size // self.page)
-            # a shared partial tail page costs one extra page (COW copy)
-            need = (total_blocks - len(pages)
-                    + (1 if matched % self.page else 0))
-            busy = (self.phase != _FREE).any()
-            gate = need + (self.low_water if busy else 0)
-            if self.alloc.free_count < gate:
-                self._reclaim(gate)
-            if self.alloc.free_count < gate:
-                for p in pages:             # park: head stays queued
-                    self.alloc.decref(p)
-                break
-            self.admit_q.pop()
-            slot = self.free_slots.pop()
-            req.out = self._resume_out.pop(req.rid, None) or []
-            self._is_resume[slot] = bool(req.out)
-            self.active[slot] = req
-            self._prompt[slot] = fill
-            self._psize[slot] = prompt.size
-            self.table[slot, :] = 0
-            for i, p in enumerate(pages):
-                self.table[slot, i] = p
-            self.n_blocks[slot] = len(pages)
-            self._ptr[slot] = matched
-            self.pos[slot] = matched
-            self.phase[slot] = _PREFILL
-            self._seq += 1
-            self._slot_seq[slot] = self._seq
-            self.stats.admitted += 1
-            if matched:
-                self.stats.prefix_hits += 1
-                self.stats.prefix_tokens_reused += matched
-            reset.append(slot)
-            new_lens[slot] = matched
-        if reset:
-            keep = np.ones(self.b, bool)
-            keep[reset] = False
-            self._reset_slots(reset, keep, new_lens)
+        with _span(self.stats, "serve.admit", "admit_s"):
+            reset: List[int] = []
+            new_lens = np.zeros(self.b, np.int64)
+            while self.free_slots and self.admit_q:
+                req = self.admit_q.peek()
+                prompt = np.asarray(req.prompt, np.int64).reshape(-1)
+                if prompt.size == 0:
+                    prompt = np.array([self.bos], np.int64)
+                resume = self._resume_out.get(req.rid)
+                if resume:
+                    # teacher-force the tokens generated before preemption;
+                    # the last one re-enters decode via the handoff channel
+                    fill = np.concatenate(
+                        [prompt, np.asarray(resume[:-1], np.int64)])
+                else:
+                    fill = prompt
+                matched, pages = 0, []
+                if self.prefix is not None:
+                    # at least one token must actually prefill (its logits
+                    # seed the first output), hence the size-1 cap
+                    matched, pages = self.prefix.lookup(
+                        fill, fill.size - 1, self.alloc)
+                total_blocks = -(-fill.size // self.page)
+                # a shared partial tail page costs one extra page (COW copy)
+                need = (total_blocks - len(pages)
+                        + (1 if matched % self.page else 0))
+                busy = (self.phase != _FREE).any()
+                gate = need + (self.low_water if busy else 0)
+                if self.alloc.free_count < gate:
+                    self._reclaim(gate)
+                if self.alloc.free_count < gate:
+                    for p in pages:             # park: head stays queued
+                        self.alloc.decref(p)
+                    break
+                self._dequeued(self.admit_q.pop())
+                slot = self.free_slots.pop()
+                req.out = self._resume_out.pop(req.rid, None) or []
+                self._is_resume[slot] = bool(req.out)
+                self.active[slot] = req
+                self._prompt[slot] = fill
+                self._psize[slot] = prompt.size
+                self.table[slot, :] = 0
+                for i, p in enumerate(pages):
+                    self.table[slot, i] = p
+                self.n_blocks[slot] = len(pages)
+                self._ptr[slot] = matched
+                self.pos[slot] = matched
+                self.phase[slot] = _PREFILL
+                self._seq += 1
+                self._slot_seq[slot] = self._seq
+                self.stats.admitted += 1
+                if matched:
+                    self.stats.prefix_hits += 1
+                    self.stats.prefix_tokens_reused += matched
+                reset.append(slot)
+                new_lens[slot] = matched
+            if reset:
+                keep = np.ones(self.b, bool)
+                keep[reset] = False
+                self._reset_slots(reset, keep, new_lens)
 
     def _reset_slots(self, reset, keep, new_lens) -> None:
         """Zero the cache lengths of freshly admitted slots; the sharded
