@@ -173,6 +173,11 @@ def decode_chunk_ref(q, k_cache, v_cache, lengths, *,
     prefill BIT-IDENTICAL to a run of single-token decode steps.  FLOPs
     are identical either way; only the K/V re-reads differ, which the
     ref oracle does not model.
+
+    This is the ``ref``-mode oracle, kept for that bit-identity (ROADMAP
+    D2), and no longer on the ``pallas`` serving path: there a chunk
+    attends through ``models.attention.grouped_chunk_attention``, one
+    grouped MXU GEMM per KV head, equal to this within float32 rounding.
     """
     import jax
 

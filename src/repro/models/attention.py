@@ -23,7 +23,7 @@ from repro.models.common import (ModelConfig, dense_init, on_each_device,
                                  rmsnorm, rmsnorm_init, rope)
 from repro.kernels.flash_attention.ops import (flash_attention, flash_decode,
                                                flash_decode_paged)
-from repro.kernels.flash_attention.ref import (attention_banded,
+from repro.kernels.flash_attention.ref import (NEG_INF, attention_banded,
                                                attention_chunked,
                                                attention_ref,
                                                decode_chunk_ref, decode_ref)
@@ -44,6 +44,59 @@ def _prefill_attention(cfg: ModelConfig, q, k, v, *, causal, window):
         return attention_chunked(q, k, v, causal=causal, window=window,
                                  chunk=cfg.attn_chunk, unroll=unroll)
     return attention_ref(q, k, v, causal=causal, window=window)
+
+
+def _chunk_attention(cfg: ModelConfig, q, k, v, qlens):
+    """Dispatch the chunked cache-fill attention: the grouped GEMM in
+    ``pallas`` mode, the per-query oracle (bit-identical to stepwise
+    decode) in ``ref`` mode."""
+    if cfg.kernel_mode == "pallas":
+        return grouped_chunk_attention(q, k, v, qlens)
+    return decode_chunk_ref(q, k, v, qlens)
+
+
+# One row block's float32 scores and probabilities, together, in bytes.
+_CHUNK_SCORE_BYTES = 256 << 20
+
+
+def grouped_chunk_attention(q, k, v, qlens):
+    """Chunk queries against a cache as one grouped GEMM per KV head.
+
+    q (B,H,C,D); k, v (B,KVH,S,D); qlens (B,C) — query i of row b
+    attends cache positions < qlens[b, i].  The same mask, float32
+    softmax and floor as :func:`decode_chunk_ref`, but the G = H/KVH
+    query heads of a KV head and the C queries are the G·C rows of one
+    matrix product with K (no repeated K/V, no loop over queries), so
+    it runs on the MXU.  bfloat16 q·K products accumulate in float32;
+    any float32 operand, and p·V always, runs at HIGHEST precision.
+    Rows go in blocks whose scores fit ``_CHUNK_SCORE_BYTES``.
+    """
+    b, h, c, d = q.shape
+    kvh, s = k.shape[1], k.shape[2]
+    g = h // kvh
+    hi = jax.lax.Precision.HIGHEST
+    scale = d ** -0.5
+
+    def row(qr, kr, vr, lr):          # (KVH,G,C,D), (KVH,S,D) x2, (C,)
+        if qr.dtype == kr.dtype == jnp.bfloat16:
+            sc = jnp.einsum("kgcd,ksd->kgcs", qr, kr,
+                            preferred_element_type=jnp.float32)
+        else:
+            sc = jnp.einsum("kgcd,ksd->kgcs", qr.astype(jnp.float32),
+                            kr.astype(jnp.float32), precision=hi)
+        mask = jnp.arange(s)[None, :] < lr[:, None]                # (C, S)
+        sc = jnp.where(mask, sc * scale, NEG_INF)
+        p = jnp.exp(sc - sc.max(-1, keepdims=True))
+        p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+        return jnp.einsum("kgcs,ksd->kgcd", p, vr.astype(jnp.float32),
+                          precision=hi)
+
+    row_bytes = 2 * 4 * h * c * s       # float32 scores + probabilities
+    rows = max(1, min(b, _CHUNK_SCORE_BYTES // row_bytes))
+    out = jax.lax.map(lambda a: row(*a),
+                      (q.reshape(b, kvh, g, c, d), k, v, qlens),
+                      batch_size=rows)
+    return out.reshape(b, h, c, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -121,6 +174,12 @@ def gqa_apply(cfg: ModelConfig, p, x, positions, *, causal: bool = True,
     a contiguous (B, KVH, NPB*PAGE, hd) view and reuses the exact
     contiguous oracle, so paged and contiguous decode are bit-identical
     whenever NPB*PAGE equals the contiguous s_max.
+
+    A chunk (S > 1, either layout) attends through
+    :func:`grouped_chunk_attention` in ``pallas`` mode, one grouped GEMM
+    per KV head over the (gathered) cache; ``ref`` mode keeps the
+    per-query oracle :func:`decode_chunk_ref`, which the grouped path
+    matches within float32 rounding, not bit for bit.
     """
     b, s, d = x.shape
     q, k, v = _project_qkv(cfg, p, x, positions)
@@ -147,7 +206,7 @@ def gqa_apply(cfg: ModelConfig, p, x, positions, *, causal: bool = True,
         else:
             kc = _gather_pages(kp, page_table)
             vc = _gather_pages(vp, page_table)
-            out = decode_chunk_ref(q, kc, vc, qlens)           # (B,H,S,hd)
+            out = _chunk_attention(cfg, q, kc, vc, qlens)       # (B,H,S,hd)
         new_cache = {"kp": kp, "vp": vp, "len": lens}
     elif s == 1 and valid is None:
         pos = cache["len"]                                     # (B,)
@@ -180,7 +239,7 @@ def gqa_apply(cfg: ModelConfig, p, x, positions, *, causal: bool = True,
             out = flash_decode(q[:, :, 0, :], kc, vc, qlens[:, 0])
             out = out[:, :, None, :]
         else:
-            out = decode_chunk_ref(q, kc, vc, qlens)           # (B,H,S,hd)
+            out = _chunk_attention(cfg, q, kc, vc, qlens)       # (B,H,S,hd)
         new_cache = {"k": kc, "v": vc, "len": lens}
 
     out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.hd)

@@ -209,6 +209,35 @@ def test_decode_chunk_ref_matches_decode_ref():
             np.asarray(decode_ref(q[:, :, i], kc, vc, lens[:, i])))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("c", [1, 5, 32])
+@pytest.mark.parametrize("g", [1, 4, 8])
+def test_grouped_chunk_attention_matches_oracle(g, c, dtype, monkeypatch):
+    """The pallas-mode chunk path (one grouped GEMM per KV head, rows in
+    blocks) against the per-query oracle: ragged per-row lengths, one
+    row at its first position and one ending at the horizon, and a
+    batch of 5 in blocks of 2 (a remainder block of 1)."""
+    from repro.kernels.flash_attention.ref import decode_chunk_ref
+    from repro.models import attention
+    b, kvh, s, d = 5, 2, 48, 16
+    h = g * kvh
+    monkeypatch.setattr(attention, "_CHUNK_SCORE_BYTES",
+                        2 * (2 * 4 * h * c * s) + 1)      # 2 rows a block
+    q = jnp.asarray(R.standard_normal((b, h, c, d)), dtype)
+    kc = jnp.asarray(R.standard_normal((b, kvh, s, d)), dtype)
+    vc = jnp.asarray(R.standard_normal((b, kvh, s, d)), dtype)
+    pos = np.array([0, s - c, *R.integers(0, s - c + 1, b - 2)])
+    lens = jnp.asarray(pos[:, None] + np.arange(1, c + 1)[None], jnp.int32)
+    out = jax.jit(attention.grouped_chunk_attention)(q, kc, vc, lens)
+    ref = jax.jit(decode_chunk_ref)(q, kc, vc, lens)
+    assert out.shape == ref.shape and out.dtype == dtype
+    # float32 rounding; a bfloat16 output may round one step apart
+    tol = (dict(rtol=1e-5, atol=1e-6) if dtype == jnp.float32
+           else dict(rtol=2.0 ** -7, atol=1e-6))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+
+
 @pytest.mark.parametrize("raw,expect", [
     (None, False),        # unset: fall through to the backend check
     ("0", False), ("false", False), ("", False), ("no", False),

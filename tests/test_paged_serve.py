@@ -135,6 +135,46 @@ def test_paged_pallas_matches_ref_mode():
     assert pal == ref
 
 
+def test_paged_chunk_step_pallas_matches_ref_mode():
+    """One ``prefill_paged`` chunk step: the pallas-mode grouped chunk
+    attention against ref mode's per-query oracle, over three slots —
+    idle (n_valid 0), mid-prompt across a page boundary, fresh — on a
+    pool filled with noise.  Logits agree to float32 rounding; the
+    scatter is shared, so lengths and the first layer's pages are
+    bit-identical (later layers' K/V inherit the rounding); the idle
+    slot's pages and length are untouched."""
+    cfg_r, m_r, params = _model(FAST_ARCH)
+    _, m_p, _ = _model(FAST_ARCH, kernel_mode="pallas")
+    b, c, page, npb = 3, 4, 8, 4
+    rng = np.random.default_rng(7)
+    pos = np.array([5, 6, 0], np.int32)            # 6..9 crosses page 1->2
+    n_valid = np.array([0, 4, 3], np.int32)
+    table = 1 + np.arange(b * npb, dtype=np.int32).reshape(b, npb)
+    cache = m_r.cache_init_paged(b, 1 + b * npb, page)
+    noise = lambda a: rng.standard_normal(a.shape).astype(a.dtype)
+    cache = [{"attn": {"kp": noise(seg["attn"]["kp"]),
+                       "vp": noise(seg["attn"]["vp"]),
+                       "len": np.broadcast_to(pos, seg["attn"]["len"].shape)}}
+             for seg in cache]
+    tok = rng.integers(0, cfg_r.vocab, (b, c)).astype(np.int32)
+    args = (tok, pos, n_valid, table)
+    logits_r, new_r = jax.jit(m_r.prefill_paged)(params, cache, *args)
+    logits_p, new_p = jax.jit(m_p.prefill_paged)(params, cache, *args)
+    np.testing.assert_allclose(np.asarray(logits_p), np.asarray(logits_r),
+                               rtol=1e-4, atol=1e-4)
+    for old, r, p in zip(cache, new_r, new_p):
+        np.testing.assert_array_equal(np.asarray(p["attn"]["len"]),
+                                      np.asarray(r["attn"]["len"]))
+        assert (np.asarray(p["attn"]["len"]) == pos + n_valid).all()
+        for key in ("kp", "vp"):
+            pr, pp = np.asarray(r["attn"][key]), np.asarray(p["attn"][key])
+            np.testing.assert_array_equal(pp[0], pr[0])
+            np.testing.assert_allclose(pp, pr, rtol=1e-4, atol=1e-4)
+            idle = table[0]                        # slot 0's pages
+            np.testing.assert_array_equal(pp[:, idle],
+                                          old["attn"][key][:, idle])
+
+
 # -- page pressure: preemption and recovery -----------------------------------
 
 
