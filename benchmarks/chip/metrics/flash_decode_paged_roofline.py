@@ -3,11 +3,11 @@ time the chip could take for the traced decode steps' attention (the
 larger of its FLOPs over peak FLOP/s and the bytes of the valid K/V
 pages, the query and the output over peak bandwidth), divided by the
 device time of the kernel's calls (``%_decode_paged_impl.N``
-custom-calls) in the trace.  Averaged over the chips."""
+custom-calls) in the trace.  Averaged over the chips.  The FLOPs and
+bytes are the configuration's family's ``paged_decode_cost``."""
 
 import statistics
 
-import flops
 import reduce_trace as T
 
 KERNEL = r"_decode_paged_impl(\.\d+)?$"
@@ -24,8 +24,8 @@ def read(ctx):
         return None
     f = b = 0.0
     for _, _, _, lens, _ in ctx.steps_in("decode"):
-        df, db = flops.paged_decode_cost(ctx.model, lens,
-                                         ctx.deployment["page"])
+        df, db = ctx.family.paged_decode_cost(ctx.model, lens,
+                                              ctx.deployment["page"])
         f, b = f + df, b + db
     if not f:
         return None

@@ -12,7 +12,8 @@ frequencies ``theta^(-i / (hd/2))``), causal softmax attention scaled by
 ``hd^-0.5`` where query head ``j`` reads KV head ``j // (H / KVH)``, an
 MLP that is SwiGLU (``silu(h Wg) * (h Wu) Wd``) or GELU with the tanh
 approximation (``gelu(h Wu) Wd``), and a final RMSNorm before the
-unembedding.  Every matrix product runs in float32 at
+unembedding, which is the embedding's transpose where the configuration
+ties the two (``tie_embeddings``).  Every matrix product runs in float32 at
 ``precision="highest"``.
 
 The reference runs over whole sequences (prompt followed by the served
@@ -37,6 +38,13 @@ import weights as W
 
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
+
+
+class Frozen(dict):
+    """A configuration's ``model`` block, hashable so jit can key on it."""
+
+    def __hash__(self):
+        return hash(tuple(sorted(self.items())))
 
 
 def _q8(a: jax.Array, axis: int) -> jax.Array:
@@ -130,7 +138,7 @@ def logits_at(model: Dict, dtype: str, seed: int,
     """Reference logits of each sequence ``seqs[i]`` at positions
     ``rows[i]`` (the positions whose next token was served), float32
     (len(rows[i]), vocab) on the host."""
-    m = W.Frozen(model)
+    m = Frozen(model)
     n = max(len(s) for s in seqs)
     s_pad = -(-n // bucket) * bucket
     tok = np.zeros((len(seqs), s_pad), np.int32)
@@ -147,7 +155,10 @@ def logits_at(model: Dict, dtype: str, seed: int,
     xr = x[sel[:, 0], sel[:, 1]]
     del x
     g = W.reference_outer(model, seed, "final_norm", dtype)
-    unembed = W.reference_outer(model, seed, "unembed", dtype)
+    if m["tie_embeddings"]:
+        unembed = W.reference_outer(model, seed, "embed", dtype).T
+    else:
+        unembed = W.reference_outer(model, seed, "unembed", dtype)
     out = np.asarray(_head(xr, g, unembed, m=m, control=control))
     splits = np.cumsum([len(r) for r in rows])[:-1]
     return np.split(out, splits)

@@ -7,9 +7,12 @@
 Run it from the root of a checkout, on a machine with the chips the cell
 asks for.  The cell (``BENCHMARK.json``) names a configuration
 (``configs/<name>.json``) and a traffic mix (``traffic/<mix>.json``);
-its metrics are readers in ``metrics/<metric>.py`` and its correctness
-limits are in ``limits/<cell>.json``.  Nothing here names a cell: a
-later change adds one by adding those files and entries.
+the configuration names its model family (``families/<family>.py``: the
+weights' shapes and the operation counts) and its plain reference; the
+cell's metrics are readers in ``metrics/<metric>.py`` and its
+correctness limits are in ``limits/<cell>.json``.  Nothing here names a
+cell or a model's shapes: a later change adds either by adding those
+files and entries.
 
 The run makes the weights from ``--seed`` on the device, serves the mix
 through the program's own ``PagedServeLoop`` with the Pallas kernels,
@@ -44,6 +47,8 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 
+import families  # noqa: E402
+
 TRACE_SECONDS = 10.0
 
 
@@ -75,6 +80,26 @@ def steal_s() -> Optional[float]:
         return None
 
 
+def keep_freed_memory() -> None:
+    """Fix glibc's allocator so that the heap keeps what it frees.
+
+    A step's logits (granite's 25 MB) are fetched into a fresh heap
+    buffer.  With glibc's defaults the heap's top is sometimes given back
+    when that buffer is freed, depending on how the process's heap lies,
+    and the next buffer then pays a first touch of every page: on a TPU
+    v5e host the decode step's argmax reads 26-30 ms instead of ~3 ms for
+    the whole of such a process.  Fixed thresholds make every process
+    keep its pages."""
+    import ctypes
+    M_TRIM_THRESHOLD, M_TOP_PAD, M_MMAP_THRESHOLD = -1, -2, -3
+    mallopt = ctypes.CDLL(None).mallopt
+    for param, value in ((M_MMAP_THRESHOLD, 32 << 20),
+                         (M_TRIM_THRESHOLD, 1 << 30),
+                         (M_TOP_PAD, 256 << 20)):
+        if mallopt(param, value) != 1:
+            raise Failed(f"mallopt({param}, {value}) refused")
+
+
 def load_json(path: Path) -> Dict:
     if not path.is_file():
         raise Failed(f"missing {path.relative_to(ROOT)}")
@@ -88,6 +113,10 @@ def load_cell(name: str, root: Path = ROOT) -> Dict:
         raise Failed(f"no workload {name!r} in BENCHMARK.json")
     cell = dict(cells[name])
     cell["config_file"] = load_json(HERE / "configs" / f"{cell['config']}.json")
+    try:
+        families.load(cell["config_file"]["model"])
+    except LookupError as e:
+        raise Failed(f"configs/{cell['config']}.json: {e}") from None
     cell["mix"] = load_json(HERE / "traffic" / f"{cell['traffic']}.json")
     cell["limits"] = load_json(HERE / "limits" / f"{name}.json")
     cell["end_to_end"] = [m for m in bench["end_to_end"]
@@ -130,6 +159,7 @@ def make_loop_class():
             self.submitted: Dict[int, float] = {}
             self.finished: Dict[int, tuple] = {}
             self.prompts: Dict[int, np.ndarray] = {}
+            self.held: List[int] = []        # pool pages held, per round
 
         # -- traffic ---------------------------------------------------------
 
@@ -183,6 +213,7 @@ def make_loop_class():
                         jax.profiler.start_trace(str(self.trace_dir))
                         self.trace_t = [time.perf_counter(), None]
                 return
+            self.held.append(self.alloc.n_pages - 1 - self.alloc.free_count)
             if (self.trace_t and self.trace_t[1] is None
                     and now >= self.t0 + min(TRACE_SECONDS, self.seconds)):
                 jax.block_until_ready(self.cache)
@@ -257,7 +288,6 @@ def warm_shapes(loop) -> None:
     step widths, the slot reset and the page copy."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
     b = loop.b
     # each program donates the cache: take the returned one each time
     for c in (loop.chunk, 1):
@@ -268,7 +298,7 @@ def warm_shapes(loop) -> None:
     loop.cache = loop._reset_paged(loop.cache, jnp.ones(b, bool),
                                    jnp.zeros(b, jnp.int32))
     loop.cache = loop._copy(loop.cache, jnp.int32(0), jnp.int32(0))
-    np.asarray(jax.block_until_ready(loop.cache)[0]["attn"]["len"])
+    jax.block_until_ready(loop.cache)
 
 
 def serve(cell: Dict, seed: int, seconds: float, trace: bool,
@@ -362,6 +392,7 @@ class Context:
     def __init__(self, cell, loop, setup_s, peaks, tr):
         self.cell = cell
         self.model = cell["config_file"]["model"]
+        self.family = families.load(self.model)
         self.deployment = cell["config_file"]["deployment"]
         self.peaks = peaks
         self.setup_s = setup_s
@@ -506,6 +537,12 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
         f"{len(loop.gcs)} collections, longest "
         f"{max(loop.gcs, default=(0.0, 0))} (s, generation); steal "
         + (f"{steal[1] - steal[0]:.2f} s" if None not in steal else "n/a"))
+    if loop.held:
+        held = statistics.fmean(loop.held)
+        log(f"pool: {held:.1f} of {loop.alloc.n_pages - 1} pages held on "
+            f"average over the window's rounds "
+            f"({100 * held / (loop.alloc.n_pages - 1):.2f}%), at most "
+            f"{max(loop.held)}")
     if ttft:
         log(f"ttft: n={len(ttft)} p50={stats.percentile(ttft, 50):.4f} s "
             f"p95={stats.percentile(ttft, 95):.4f} s")
@@ -556,6 +593,7 @@ def main(argv=None) -> None:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     try:
+        keep_freed_memory()
         if not (ROOT / "src" / "repro").is_dir():
             raise Failed("no src/repro in this checkout: run from the root "
                          "of a checkout of the repository")

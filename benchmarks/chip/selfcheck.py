@@ -7,7 +7,8 @@ Checks, and prints one line each:
 
 1. the plain reference (``reference.py``) against the program's own
    full-sequence forward (``repro.models``) at smoke widths, in float32,
-   for both configurations' kinds, with the weights of ``weights.py``;
+   for both configurations' kinds (qwen3-4b's head tied), with the
+   weights of ``weights.py``;
    and that the served weights equal the reference's, rounded;
 2. the trace reduction (``reduce_trace.py``) on a hand-made trace
    whose answers are known, and on the small recorded chip trace in
@@ -37,9 +38,11 @@ def check_reference() -> None:
     from repro.configs import get_config
     from repro.models.registry import build_model
 
-    for arch, mlp in (("qwen3-4b", "swiglu"), ("granite-34b", "gelu")):
-        cfg = get_config(arch, smoke=True)
-        m = dict(n_layers=cfg.n_layers, d_model=cfg.d_model,
+    for arch, mlp, tied in (("qwen3-4b", "swiglu", True),
+                            ("granite-34b", "gelu", False)):
+        cfg = get_config(arch, smoke=True, tie_embeddings=tied)
+        m = dict(family="dense", tie_embeddings=tied,
+                 n_layers=cfg.n_layers, d_model=cfg.d_model,
                  n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                  head_dim=cfg.hd, d_ff=cfg.d_ff, vocab=cfg.vocab,
                  qk_norm=cfg.qk_norm, mlp=mlp, rope_theta=cfg.rope_theta,
@@ -65,7 +68,8 @@ def check_reference() -> None:
         d = max(np.abs(ref[0] - lg[0]).max(),
                 np.abs(ref[1] - lg[1][5:30]).max())
         ok = d < 1e-4 and same
-        print(f"reference vs repro.models ({arch} smoke, float32): max "
+        print(f"reference vs repro.models ({arch} smoke"
+              f"{', tied head' if tied else ''}, float32): max "
               f"|logit diff| {d:.3g}; served weights = rounded reference "
               f"weights: {same} -> {'ok' if ok else 'FAIL'}")
         assert ok
