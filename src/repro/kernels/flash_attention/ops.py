@@ -56,14 +56,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "rif", "interpret",
-                                              "method"))
-def _decode_impl(q, k_cache, v_cache, lengths, *, bk, rif, interpret, method):
+                                              "method", "scale"))
+def _decode_impl(q, k_cache, v_cache, lengths, *, bk, rif, interpret, method,
+                 scale):
     b, h, d = q.shape
     kvh, s = k_cache.shape[1], k_cache.shape[2]
     g = h // kvh
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     if method == "ref":
-        return decode_ref(q, k_cache, v_cache, lengths)
+        return decode_ref(q, k_cache, v_cache, lengths, scale=scale)
     sp = round_up(s, bk)
     if sp != s:
         pad = ((0, 0), (0, 0), (0, sp - s), (0, 0))
@@ -78,12 +79,13 @@ def _decode_impl(q, k_cache, v_cache, lengths, *, bk, rif, interpret, method):
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  lengths: jax.Array, *, bk: Optional[int] = None,
                  rif: Optional[int] = None, method: str = "pallas",
+                 scale: Optional[float] = None,
                  interpret: Optional[bool] = None) -> jax.Array:
     """One-token decode: q (B,H,D) against caches (B,KVH,S,D).
 
     ``bk``/``rif`` left ``None`` resolve explicit → tune cache →
     analytic (bk 128; ``plan_rif`` over one (bk, d) block's byte
-    size)."""
+    size).  ``scale`` multiplies q·k (default ``D ** -0.5``)."""
     interp = resolve_interpret(interpret)
     if bk is None or rif is None:
         knobs = tuned_knobs("flash_decode", (k_cache.shape[2], q.shape[2]),
@@ -93,16 +95,17 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     note_knobs("flash_decode", (k_cache.shape[2], q.shape[2]), bk=bk,
                rif=rif)
     return _decode_impl(q, k_cache, v_cache, lengths, bk=bk, rif=rif,
-                        interpret=interp, method=method)
+                        interpret=interp, method=method, scale=scale)
 
 
-@functools.partial(jax.jit, static_argnames=("rif", "interpret", "method"))
+@functools.partial(jax.jit, static_argnames=("rif", "interpret", "method",
+                                              "scale"))
 def _decode_paged_impl(q, k_pages, v_pages, page_table, lengths, *,
-                       rif, interpret, method):
+                       rif, interpret, method, scale):
     b, h, d = q.shape
     kvh = k_pages.shape[1]
     g = h // kvh
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     if method == "ref":
         # reconstruct contiguous caches from pages for the oracle
         np_, _, page, _ = k_pages.shape
@@ -110,7 +113,7 @@ def _decode_paged_impl(q, k_pages, v_pages, page_table, lengths, *,
         kc = kc.transpose(0, 2, 1, 3, 4).reshape(b, kvh, -1, d)
         vc = jnp.take(v_pages, page_table, axis=0)
         vc = vc.transpose(0, 2, 1, 3, 4).reshape(b, kvh, -1, d)
-        return decode_ref(q, kc, vc, lengths)
+        return decode_ref(q, kc, vc, lengths, scale=scale)
     qg = q.reshape(b, kvh, g, d)
     out = _k.flash_decode_paged(qg, k_pages, v_pages,
                                 page_table.astype(jnp.int32),
@@ -121,11 +124,13 @@ def _decode_paged_impl(q, k_pages, v_pages, page_table, lengths, *,
 
 def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
                        rif: Optional[int] = None, method: str = "pallas",
+                       scale: Optional[float] = None,
                        interpret: Optional[bool] = None) -> jax.Array:
     """Paged decode: pages (NP,KVH,PAGE,D), page_table (B, S/PAGE) int32.
 
     ``rif=None`` resolves the page-ring depth via the tune cache, then
-    ``plan_rif`` over one page's byte size."""
+    ``plan_rif`` over one page's byte size.  ``scale`` multiplies q·k
+    (default ``D ** -0.5``)."""
     interp = resolve_interpret(interpret)
     if rif is None:
         rif = tuned_knobs("flash_decode_paged",
@@ -136,4 +141,5 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
     note_knobs("flash_decode_paged", (k_pages.shape[2], q.shape[2]),
                rif=rif)
     return _decode_paged_impl(q, k_pages, v_pages, page_table, lengths,
-                              rif=rif, interpret=interp, method=method)
+                              rif=rif, interpret=interp, method=method,
+                              scale=scale)

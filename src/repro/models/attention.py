@@ -7,9 +7,9 @@ Both run in three modes:
 
 The KV block stream of the flash kernel is the decoupled-load path
 (docs/architecture.md §"TPU adaptation"); MLA caches the *compressed
-latent* so the decoupled
-fetch reads kv_lora_rank + rope_dim bytes per token instead of
-2 * KVH * head_dim.
+latent* and attends to it in the absorbed form (one KV head), so the
+decoupled fetch reads kv_lora_rank + rope_dim values per token instead
+of 2 * KVH * head_dim.
 """
 
 from __future__ import annotations
@@ -19,8 +19,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import (ModelConfig, dense_init, on_each_device,
-                                 rmsnorm, rmsnorm_init, rope)
+from repro.models.common import (ModelConfig, attn_mscale, dense_init,
+                                 on_each_device, rmsnorm, rmsnorm_init, rope)
 from repro.kernels.flash_attention.ops import (flash_attention, flash_decode,
                                                flash_decode_paged)
 from repro.kernels.flash_attention.ref import (NEG_INF, attention_banded,
@@ -46,20 +46,20 @@ def _prefill_attention(cfg: ModelConfig, q, k, v, *, causal, window):
     return attention_ref(q, k, v, causal=causal, window=window)
 
 
-def _chunk_attention(cfg: ModelConfig, q, k, v, qlens):
+def _chunk_attention(cfg: ModelConfig, q, k, v, qlens, scale=None):
     """Dispatch the chunked cache-fill attention: the grouped GEMM in
     ``pallas`` mode, the per-query oracle (bit-identical to stepwise
     decode) in ``ref`` mode."""
     if cfg.kernel_mode == "pallas":
-        return grouped_chunk_attention(q, k, v, qlens)
-    return decode_chunk_ref(q, k, v, qlens)
+        return grouped_chunk_attention(q, k, v, qlens, scale=scale)
+    return decode_chunk_ref(q, k, v, qlens, scale=scale)
 
 
 # One row block's float32 scores and probabilities, together, in bytes.
 _CHUNK_SCORE_BYTES = 256 << 20
 
 
-def grouped_chunk_attention(q, k, v, qlens):
+def grouped_chunk_attention(q, k, v, qlens, scale=None):
     """Chunk queries against a cache as one grouped GEMM per KV head.
 
     q (B,H,C,D); k, v (B,KVH,S,D); qlens (B,C) — query i of row b
@@ -69,13 +69,14 @@ def grouped_chunk_attention(q, k, v, qlens):
     matrix product with K (no repeated K/V, no loop over queries), so
     it runs on the MXU.  bfloat16 q·K products accumulate in float32;
     any float32 operand, and p·V always, runs at HIGHEST precision.
-    Rows go in blocks whose scores fit ``_CHUNK_SCORE_BYTES``.
+    Rows go in blocks whose scores fit ``_CHUNK_SCORE_BYTES``.  ``scale``
+    multiplies q·k (default ``D ** -0.5``).
     """
     b, h, c, d = q.shape
     kvh, s = k.shape[1], k.shape[2]
     g = h // kvh
     hi = jax.lax.Precision.HIGHEST
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
 
     def row(qr, kr, vr, lr):          # (KVH,G,C,D), (KVH,S,D) x2, (C,)
         if qr.dtype == kr.dtype == jnp.bfloat16:
@@ -316,30 +317,11 @@ def _scatter_chunk_pages(pages: jnp.ndarray, new: jnp.ndarray,
         vals.astype(pages.dtype))
 
 
-def _scatter_vec_pages(pages: jnp.ndarray, new: jnp.ndarray,
-                       pos: jnp.ndarray, valid: jnp.ndarray,
-                       page_table: jnp.ndarray) -> jnp.ndarray:
-    """pages (NP, PAGE, D); new (B, C, D) — the MLA latent variant."""
-    page = pages.shape[1]
-    blk, off = _page_targets(page, page_table.shape[1], pos, valid)
-    pg = jnp.where(valid, jnp.take_along_axis(page_table, blk, axis=1), 0)
-    return pages.at[pg.reshape(-1), off.reshape(-1), :].set(
-        new.reshape(-1, new.shape[-1]).astype(pages.dtype))
-
-
 def _gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     """(NP, KVH, PAGE, hd), (B, NPB) -> contiguous (B, KVH, NPB*PAGE, hd)."""
     g = jnp.take(pages, page_table, axis=0)        # (B, NPB, KVH, PAGE, hd)
     b, npb, kvh, page, hd = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(b, kvh, npb * page, hd)
-
-
-def _gather_vec_pages(pages: jnp.ndarray, page_table: jnp.ndarray
-                      ) -> jnp.ndarray:
-    """(NP, PAGE, D), (B, NPB) -> contiguous (B, NPB*PAGE, D)."""
-    g = jnp.take(pages, page_table, axis=0)        # (B, NPB, PAGE, D)
-    b, npb, page, d = g.shape
-    return g.reshape(b, npb * page, d)
 
 
 # cross attention (enc-dec) ---------------------------------------------------
@@ -426,106 +408,112 @@ def _mla_q(cfg, p, x):
     return q[..., :dn], q[..., dn:]            # nope (B,S,H,dn), rope (B,S,H,dr)
 
 
+def mla_latent_lanes(cfg: ModelConfig) -> int:
+    """Lanes of one cached MLA latent: ``c_kv`` (kv_lora_rank) and the
+    rotated key (qk_rope_dim) side by side, zero-padded to a multiple of
+    128 (DeepSeek-V2: 512 + 64 -> 640)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_dim) // 128) * 128
+
+
 def mla_apply(cfg: ModelConfig, p, x, positions, *, causal: bool = True,
               cache: Optional[Dict[str, Any]] = None,
               valid: Optional[jnp.ndarray] = None,
               page_table: Optional[jnp.ndarray] = None):
-    """MLA attention.  cache = {"ckv": (B,Smax,r), "kr": (B,Smax,dr),
-    "len": (B,)} — the compressed-latent cache (the MLA memory win) —
-    or the paged layout {"ckvp": (NP,PAGE,r), "krp": (NP,PAGE,dr),
-    "len": (B,)} with a ``page_table`` (B, NPB): MLA pages the *latents*
-    (the decoupled fetch reads r + dr bytes per token from the pool),
-    gathers them contiguous, and up-projects exactly as the contiguous
-    path does, so paged decode is bit-identical in both kernel modes
-    whenever NPB*PAGE equals the contiguous s_max.
-    S > 1 (or an explicit ``valid`` mask) with a cache is the chunked
-    cache-fill path; see :func:`gqa_apply`."""
+    """MLA attention.
+
+    Without a cache (training, the uncached forward) the latent is
+    up-projected to per-head keys and values, as the paper writes it.
+
+    With a cache, attention runs in the absorbed form, which is
+    multi-query attention on one KV head.  Each token caches its
+    normalised latent ``c_kv`` and its rotated key side by side
+    (:func:`mla_latent_lanes` lanes: ``c_kv | k_rope | 0``); head h's
+    query is ``[W_uk,h q_nope,h | q_rope,h | 0]``, the value is the same
+    latent, and the head's output is ``W_uv,h`` applied to the first
+    kv_lora_rank lanes of the attention output.  The cache is
+    ``{"lat": (B, 1, Smax, L), "len": (B,)}`` or the paged
+    ``{"latp": (NP, 1, PAGE, L), "len": (B,)}`` with a ``page_table``
+    (B, NPB), and both go through GQA's one-KV-head layouts and kernels
+    (see :func:`gqa_apply`): ``flash_decode_paged`` / ``flash_decode``
+    for a ``pallas`` decode step, :func:`grouped_chunk_attention` for a
+    ``pallas`` chunk, the per-query oracle in ``ref`` mode.  Every path
+    scales q·k by ``(qk_nope + qk_rope) ** -0.5`` times YaRN's
+    ``mscale ** 2`` (:func:`~repro.models.common.attn_mscale`)."""
     b, s, d = x.shape
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope, cfg.qk_rope_dim, cfg.v_hd
     r = cfg.kv_lora_rank
     dt = cfg.adtype
+    mscale = attn_mscale(cfg)
 
     q_nope, q_rope = _mla_q(cfg, p, x)
     q_rope = rope(q_rope.transpose(0, 2, 1, 3), positions[:, None, :],
-                  cfg.rope_theta)                               # (B,H,S,dr)
+                  cfg.rope_theta, cfg)                          # (B,H,S,dr)
     q_nope = q_nope.transpose(0, 2, 1, 3)                       # (B,H,S,dn)
 
     ckv = rmsnorm(x @ p["w_dkv"].astype(dt), p["kv_norm"], cfg.norm_eps)
     kr = rope((x @ p["w_kr"].astype(dt))[:, None, :, :],
-              positions[:, None, :], cfg.rope_theta)            # (B,1,S,dr)
-
-    paged = cache is not None and "ckvp" in cache
-    chunked = cache is not None and not (s == 1 and valid is None)
-    if paged:
-        if page_table is None:
-            raise ValueError("paged MLA cache requires a page_table")
-        pos = cache["len"]
-        if valid is None:
-            valid = jnp.ones((b, s), bool)
-        ckv_p = _pool_constraint(cfg, _scatter_vec_pages(
-            cache["ckvp"], ckv, pos, valid, page_table))
-        kr_p = _pool_constraint(cfg, _scatter_vec_pages(
-            cache["krp"], kr[:, 0], pos, valid, page_table))
-        lens = pos + valid.sum(-1).astype(pos.dtype)
-        ckv_full = _gather_vec_pages(ckv_p, page_table)         # (B,Slog,r)
-        kr_full = _gather_vec_pages(kr_p, page_table)[:, None]  # (B,1,Slog,dr)
-        new_cache = {"ckvp": ckv_p, "krp": kr_p, "len": lens}
-        s_kv = ckv_full.shape[1]
-        chunked = True      # paged decode always takes the masked-chunk path
-    elif cache is not None and not chunked:
-        pos = cache["len"]
-        ckv_c = _scatter_vec(cache["ckv"], ckv, pos)            # (B,Smax,r)
-        kr_c = _scatter_vec(cache["kr"], kr[:, 0], pos)         # (B,Smax,dr)
-        lens = pos + 1
-        ckv_full, kr_full = ckv_c, kr_c[:, None]
-        new_cache = {"ckv": ckv_c, "kr": kr_c, "len": lens}
-        s_kv = ckv_c.shape[1]
-    elif chunked:
-        pos = cache["len"]
-        if valid is None:
-            valid = jnp.ones((b, s), bool)
-        ckv_c = _scatter_vec_chunk(cache["ckv"], ckv, pos, valid)
-        kr_c = _scatter_vec_chunk(cache["kr"], kr[:, 0], pos, valid)
-        lens = pos + valid.sum(-1).astype(pos.dtype)
-        ckv_full, kr_full = ckv_c, kr_c[:, None]
-        new_cache = {"ckv": ckv_c, "kr": kr_c, "len": lens}
-        s_kv = ckv_c.shape[1]
-    else:
-        ckv_full, kr_full = ckv, kr
-        new_cache = None
-        s_kv = s
-
-    # up-project latents to per-head K/V (decode recomputes from latents —
-    # the decoupled fetch reads only r + dr per token)
-    k_nope = (ckv_full @ p["w_uk"].astype(dt)).reshape(b, s_kv, h, dn)
-    v = (ckv_full @ p["w_uv"].astype(dt)).reshape(b, s_kv, h, dv)
-    k_nope = k_nope.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(kr_full, (b, h, s_kv, dr)).astype(dt)], -1)
-    qk = jnp.concatenate([q_nope, q_rope], -1)                  # (B,H,S,dn+dr)
+              positions[:, None, :], cfg.rope_theta, cfg)       # (B,1,S,dr)
 
     if cache is None:
-        out = _prefill_attention(cfg, qk, k, v_pad_to(v, k.shape[-1]),
+        k_nope = (ckv @ p["w_uk"].astype(dt)).reshape(b, s, h, dn)
+        v = (ckv @ p["w_uv"].astype(dt)).reshape(b, s, h, dv)
+        k = jnp.concatenate(
+            [k_nope.transpose(0, 2, 1, 3),
+             jnp.broadcast_to(kr, (b, h, s, dr)).astype(dt)], -1)
+        qk = jnp.concatenate([q_nope, q_rope], -1)              # (B,H,S,dn+dr)
+        if mscale != 1.0:
+            qk = qk * mscale
+        out = _prefill_attention(cfg, qk, k,
+                                 v_pad_to(v.transpose(0, 2, 1, 3), dn + dr),
                                  causal=causal, window=None)[..., :dv]
-    elif chunked:
-        qlens = pos[:, None] + jnp.arange(1, s + 1, dtype=pos.dtype)[None]
-        if s == 1 and cfg.kernel_mode == "pallas":
-            out = on_each_device(cfg, flash_decode, qk[:, :, 0, :], k,
-                                 v_pad_to(v, k.shape[-1]), qlens[:, 0])
-            out = out[..., :dv][:, :, None, :]
-        else:
-            out = decode_chunk_ref(qk, k, v_pad_to(v, k.shape[-1]),
-                                   qlens)[..., :dv]            # (B,H,S,dv)
+        new_cache = None
     else:
-        qd = qk[:, :, 0, :]
-        if cfg.kernel_mode == "pallas":
-            out = flash_decode(qd, k, v_pad_to(v, k.shape[-1]),
-                               new_cache["len"])[..., :dv]
+        scale = (dn + dr) ** -0.5 * mscale
+        pad = mla_latent_lanes(cfg) - r - dr
+        lat = jnp.concatenate([ckv[:, None], kr.astype(ckv.dtype),
+                               jnp.zeros((b, 1, s, pad), ckv.dtype)], -1)
+        q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope,
+                           p["w_uk"].astype(dt).reshape(r, h, dn))
+        q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype),
+                             jnp.zeros((b, h, s, pad), q_lat.dtype)], -1)
+        pos = cache["len"]                                      # (B,)
+        qlens = pos[:, None] + jnp.arange(1, s + 1, dtype=pos.dtype)[None]
+        pallas = cfg.kernel_mode == "pallas"
+        if "latp" in cache:
+            if page_table is None:
+                raise ValueError("paged MLA cache requires a page_table")
+            if valid is None:
+                valid = jnp.ones((b, s), bool)
+            pool = _pool_constraint(cfg, _scatter_chunk_pages(
+                cache["latp"], lat, pos, valid, page_table))
+            if s == 1 and pallas:
+                out = on_each_device(
+                    cfg, lambda *a: flash_decode_paged(*a, scale=scale),
+                    q[:, :, 0], pool, pool, page_table, qlens[:, 0])
+                out = out[:, :, None]
+            else:
+                lc = _gather_pages(pool, page_table)
+                out = _chunk_attention(cfg, q, lc, lc, qlens, scale)
+            new_cache = {"latp": pool,
+                         "len": pos + valid.sum(-1).astype(pos.dtype)}
+        elif s == 1 and valid is None:
+            lc = _scatter_token(cache["lat"], lat, pos)
+            decode = flash_decode if pallas else decode_ref
+            out = decode(q[:, :, 0], lc, lc, pos + 1, scale=scale)[:, :, None]
+            new_cache = {"lat": lc, "len": pos + 1}
         else:
-            out = decode_ref(qd, k, v_pad_to(v, k.shape[-1]),
-                             new_cache["len"])[..., :dv]
-        out = out[:, :, None, :]
+            if valid is None:
+                valid = jnp.ones((b, s), bool)
+            lc = _scatter_chunk(cache["lat"], lat, pos, valid)
+            if s == 1 and pallas:
+                out = flash_decode(q[:, :, 0], lc, lc, qlens[:, 0],
+                                   scale=scale)[:, :, None]
+            else:
+                out = _chunk_attention(cfg, q, lc, lc, qlens, scale)
+            new_cache = {"lat": lc,
+                         "len": pos + valid.sum(-1).astype(pos.dtype)}
+        out = jnp.einsum("bhsr,rhv->bhsv", out[..., :r],
+                         p["w_uv"].astype(dt).reshape(r, h, dv))
 
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
     return out @ p["wo"].astype(dt), new_cache
@@ -538,22 +526,3 @@ def v_pad_to(v: jnp.ndarray, d: int) -> jnp.ndarray:
     pad = [(0, 0)] * (v.ndim - 1) + [(0, d - v.shape[-1])]
     return jnp.pad(v, pad)
 
-
-def _scatter_vec(cache: jnp.ndarray, new: jnp.ndarray,
-                 pos: jnp.ndarray) -> jnp.ndarray:
-    """cache (B, Smax, D); new (B, 1, D); pos (B,)."""
-    smax = cache.shape[1]
-    onehot = (jnp.arange(smax)[None, :] == pos[:, None])[..., None]
-    return jnp.where(onehot, new.astype(cache.dtype), cache)
-
-
-def _scatter_vec_chunk(cache: jnp.ndarray, new: jnp.ndarray,
-                       pos: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
-    """cache (B, Smax, D); new (B, C, D); pos (B,); valid (B, C)."""
-    smax, c = cache.shape[1], new.shape[1]
-    tgt = pos[:, None] + jnp.arange(c, dtype=pos.dtype)[None, :]   # (B, C)
-    onehot = ((tgt[:, :, None] == jnp.arange(smax)[None, None, :])
-              & valid[:, :, None])                             # (B, C, Smax)
-    upd = jnp.einsum("bcs,bcd->bsd", onehot.astype(cache.dtype),
-                     new.astype(cache.dtype))
-    return jnp.where(onehot.any(1)[..., None], upd, cache)

@@ -4,7 +4,7 @@ stacks can lax.scan over homogeneous segments (common.LayerSpec)."""
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -12,10 +12,14 @@ import jax.numpy as jnp
 from repro.models import attention as attn
 from repro.models.common import ModelConfig, rmsnorm, rmsnorm_init
 from repro.models.mlp import mlp_apply, mlp_init
-from repro.models.moe import moe_apply, moe_init
+from repro.models.moe import moe_apply, moe_apply_held, moe_init
 from repro.models.rwkv import (rwkv_channel_apply, rwkv_channel_init,
                                rwkv_time_apply, rwkv_time_init)
 from repro.models.ssm import ssm_apply, ssm_init, ssm_init_state
+
+
+# where an expert layer's paged cache keeps the last step's counters
+_COUNTERS = "stats"
 
 
 def _attn_init(cfg: ModelConfig, key):
@@ -94,16 +98,21 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, *,
                             cache=None if cache is None else cache["attn"],
                             valid=valid, page_table=page_table)
         x = x + h
-        if kind == "moe":
-            # decode: dropless dispatch (capacity drops would make decode
-            # diverge from prefill); train: GShard-style capacity factor
-            cf = float(cfg.n_experts) if cache is not None else 0.0
-            x = x + moe_apply(cfg, p["moe"], rmsnorm(x, p["ln2"], eps),
-                              capacity_factor=cf)
+        stats = None
+        if kind == "moe" and cache is not None:
+            # serving: the held-expert layer, dropless (capacity drops
+            # would make decode diverge from prefill)
+            y, stats = moe_apply_held(cfg, p["moe"],
+                                      rmsnorm(x, p["ln2"], eps), valid)
+            x = x + y
+        elif kind == "moe":
+            x = x + moe_apply(cfg, p["moe"], rmsnorm(x, p["ln2"], eps))
         else:
             x = x + mlp_apply(cfg, p["mlp"], rmsnorm(x, p["ln2"], eps))
         if cache is not None:
             new_cache = {"attn": ac}
+            if _COUNTERS in cache:
+                new_cache[_COUNTERS] = stats
 
     elif kind in ("hymba", "hymba_global"):
         window = None if kind == "hymba_global" else cfg.window
@@ -160,8 +169,8 @@ def block_cache_init(cfg: ModelConfig, kind: str, batch: int, s_max: int
     hd, kvh = cfg.hd, cfg.n_kv_heads
     if kind in ("attn", "moe", "enc", "xattn", "hymba", "hymba_global"):
         if cfg.attn_kind == "mla":
-            ac = {"ckv": jnp.zeros((batch, s_max, cfg.kv_lora_rank), cfg.adtype),
-                  "kr": jnp.zeros((batch, s_max, cfg.qk_rope_dim), cfg.adtype),
+            ac = {"lat": jnp.zeros((batch, 1, s_max,
+                                    attn.mla_latent_lanes(cfg)), cfg.adtype),
                   "len": jnp.zeros((batch,), jnp.int32)}
         else:
             # NOTE: sliding-window layers could use a ring buffer of size
@@ -190,21 +199,40 @@ def block_cache_init_paged(cfg: ModelConfig, kind: str, batch: int,
     attended to because ``len`` masks them.  Only pure-attention kinds
     page; recurrent state (ssm/rwkv/hymba) has no growing KV to page.
 
-    Under sharded serving the pool leaves (``kp``/``vp``/``ckvp``/
-    ``krp``) shard their page dim over the data axis — see the
-    ``_PAGED_POOL`` rule in ``parallel/sharding.py`` and the in-jit
-    ``_pool_constraint`` in ``attention.py``; ``len`` stays replicated
-    (it is the scheduler's per-slot control state).
+    Under sharded serving the pool leaves (``kp``/``vp``/``latp``)
+    shard their page dim over the data axis — see the ``_PAGED_POOL``
+    rule in ``parallel/sharding.py`` and the in-jit ``_pool_constraint``
+    in ``attention.py``; ``len`` stays replicated (it is the scheduler's
+    per-slot control state).  An expert layer's cache also carries the
+    last step's counters (``moe_rows``, ``moe_blocks``: int32 scalars),
+    which the serve loop reads through :func:`step_counters`.
     """
     hd, kvh = cfg.hd, cfg.n_kv_heads
     if kind not in ("attn", "moe"):
         raise ValueError(f"block kind {kind!r} has no paged cache")
     if cfg.attn_kind == "mla":
-        ac = {"ckvp": jnp.zeros((n_pages, page, cfg.kv_lora_rank), cfg.adtype),
-              "krp": jnp.zeros((n_pages, page, cfg.qk_rope_dim), cfg.adtype),
+        ac = {"latp": jnp.zeros((n_pages, 1, page,
+                                 attn.mla_latent_lanes(cfg)), cfg.adtype),
               "len": jnp.zeros((batch,), jnp.int32)}
     else:
         ac = {"kp": jnp.zeros((n_pages, kvh, page, hd), cfg.adtype),
               "vp": jnp.zeros((n_pages, kvh, page, hd), cfg.adtype),
               "len": jnp.zeros((batch,), jnp.int32)}
+    if kind == "moe":
+        zero = jnp.zeros((), jnp.int32)
+        return {"attn": ac,
+                _COUNTERS: {"moe_rows": zero, "moe_blocks": zero}}
     return {"attn": ac}
+
+
+def step_counters(caches) -> Dict[str, List[Any]]:
+    """The counters the last step left in a model's paged caches (one
+    block cache per segment): each counter by name, one array per
+    segment that keeps it.  Empty for a model with no expert layer."""
+    out: Dict[str, List[Any]] = {}
+    if isinstance(caches, list):
+        for seg in caches:
+            if isinstance(seg, dict):
+                for key, val in seg.get(_COUNTERS, {}).items():
+                    out.setdefault(key, []).append(val)
+    return out

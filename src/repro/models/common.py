@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 
@@ -56,6 +57,13 @@ class ModelConfig:
     qk_rope_dim: int = 64
     qk_nope_dim: int = 0              # 0 -> head_dim
     v_head_dim: int = 0               # 0 -> head_dim
+    # YaRN rope scaling (DeepSeek-V2 ``rope_scaling``); factor 0 = off
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # mlp
     mlp_kind: str = "swiglu"          # swiglu | relu | gelu
@@ -68,6 +76,9 @@ class ModelConfig:
     capacity_factor: float = 1.25
     first_dense_layers: int = 0       # leading dense-FFN layers (deepseek)
     pad_experts_to: int = 0           # pad expert dim for EP divisibility
+    norm_topk_prob: bool = True       # renormalise the top-k gates to 1
+    # experts [lo, hi) this device holds (expert parallelism); None = all
+    expert_range: Optional[Tuple[int, int]] = None
 
     # SSM / hybrid
     ssm_state: int = 16
@@ -104,9 +115,19 @@ class ModelConfig:
     # mesh axis inside jit (None = leave placement to propagation)
     mesh_pool_axis: Optional[str] = None
 
+    def __post_init__(self):
+        if self.expert_range is not None:        # a JSON list is welcome
+            object.__setattr__(self, "expert_range",
+                               tuple(int(e) for e in self.expert_range))
+
     @property
     def n_experts_padded(self) -> int:
         return max(self.n_experts, self.pad_experts_to)
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """[lo, hi) of the experts whose weights this device holds."""
+        return self.expert_range or (0, self.n_experts_padded)
 
     # ----- derived -----
     @property
@@ -196,16 +217,63 @@ def rmsnorm(x: jnp.ndarray, g: jnp.ndarray, eps: float) -> jnp.ndarray:
     return (out * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x (..., S, D_even); positions (..., S)."""
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         cfg: Optional[ModelConfig] = None) -> jnp.ndarray:
+    """x (..., S, D_even); positions (..., S).  With a ``cfg`` that sets
+    ``yarn_factor``, the frequencies and the cos/sin factor are YaRN's
+    (:func:`yarn_inv_freq`, :func:`yarn_mscale`)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    yarn = cfg is not None and cfg.yarn_factor > 0
+    if yarn:
+        freqs = jnp.asarray(yarn_inv_freq(cfg, d, theta))
+    else:
+        freqs = jnp.exp(-math.log(theta)
+                        * jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freqs        # (..., S, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn:
+        m = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: 0.1 mscale ln(factor) + 1."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int, theta: float) -> np.ndarray:
+    """YaRN's rotary frequencies for ``dim`` rotated dims (float32,
+    ``dim / 2`` of them): the original ``theta^(-2i/dim)`` below the
+    correction range, those divided by ``yarn_factor`` above it, and a
+    linear ramp between (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    def corr_dim(rot):
+        return (dim * math.log(cfg.yarn_original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(dim // 2, dtype=np.float32)
+    extra = 1.0 / theta ** (2 * i / dim)
+    ramp = np.clip((i - low) / (high - low), 0, 1)
+    return (extra / cfg.yarn_factor * ramp
+            + extra * (1 - ramp)).astype(np.float32)
+
+
+def attn_mscale(cfg: ModelConfig) -> float:
+    """Factor on the softmax scale: YaRN's ``mscale(mscale_all_dim)^2``
+    where the config sets ``yarn_mscale_all_dim``, else 1."""
+    if cfg.yarn_factor > 0 and cfg.yarn_mscale_all_dim:
+        return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return 1.0
 
 
 def activation(kind: str, x: jnp.ndarray) -> jnp.ndarray:

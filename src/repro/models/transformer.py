@@ -145,7 +145,7 @@ def lm_cache_init_paged(cfg: ModelConfig, batch: int, n_pages: int,
     return caches
 
 
-_PAGE_KEYS = ("kp", "vp", "ckvp", "krp")
+_PAGE_KEYS = ("kp", "vp", "latp")
 
 
 def lm_copy_pages(caches: List[Any], src: jnp.ndarray, dst: jnp.ndarray

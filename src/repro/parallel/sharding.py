@@ -140,7 +140,7 @@ def batch_sharding(mesh: Mesh, ndim: int, rules: Optional[ShardingRules] = None
 
 # paged KV pool leaves: stacked (layer_count, n_pages, ...); dim 1 is
 # the page-pool dim, the unit the paged serve loop allocates/migrates
-_PAGED_POOL = ("kp", "vp", "ckvp", "krp")
+_PAGED_POOL = ("kp", "vp", "latp")
 
 
 def cache_shardings(cache_shape: Any, mesh: Mesh,
@@ -173,14 +173,10 @@ def cache_shardings(cache_shape: Any, mesh: Mesh,
             if b % dp_size == 0 and b > 0:
                 return NamedSharding(mesh, P(None, dp, *([None] * (len(shape) - 2))))
             # sequence-parallel fallback: shard the time axis over data
-            if names and names[-1] in ("k", "v") and len(shape) == 5:
+            if names and names[-1] in ("k", "v", "lat") and len(shape) == 5:
                 s = shape[3]
                 if rules.seq_shard_cache and _divisible(s, mesh, "data"):
                     return NamedSharding(mesh, P(None, None, None, "data", None))
-            if names and names[-1] in ("ckv", "kr") and len(shape) == 4:
-                s = shape[2]
-                if rules.seq_shard_cache and _divisible(s, mesh, "data"):
-                    return NamedSharding(mesh, P(None, None, "data", None))
         return NamedSharding(mesh, P(*([None] * len(shape))))
 
     return jax.tree_util.tree_map_with_path(f, cache_shape)

@@ -72,6 +72,7 @@ import numpy as np
 
 from repro.channels import LocalChannel
 from repro.core.trace import Tracer
+from repro.models.blocks import step_counters
 
 # slot phases
 _FREE, _PREFILL, _HANDOFF, _DECODE = 0, 1, 2, 3
@@ -203,6 +204,11 @@ class ServeStats:
     # many were taken (a preempted request counts again)
     queue_wait_s: float = 0.0
     queue_waits: int = 0
+    # expert layers (paged serving): rows routed to the held experts and
+    # the non-empty grouped_matmul blocks they filled, summed over
+    # layers and steps (``blocks.step_counters`` of the step's cache)
+    moe_rows: int = 0
+    moe_blocks: int = 0
 
 
 class PageAllocator:
@@ -418,6 +424,11 @@ class ServeLoop:
         with _span(st, "step.copy", "copy_s"):
             out = np.asarray(logits)
         st.logits_bytes += out.nbytes
+        # the step's own counters, ready with its logits: fetched apart
+        # from them, so step.copy times the logits alone
+        counters = jax.device_get(step_counters(self.cache))
+        for key, vals in counters.items():
+            setattr(st, key, getattr(st, key) + sum(int(v.sum()) for v in vals))
         return out
 
     def _dequeued(self, req: Request) -> None:

@@ -71,18 +71,17 @@ def test_paged_pool_replicates_never_seq_shards():
     # 33 pages % 8 != 0: replicate — sequence sharding would split
     # inside a page, and the batch rule must not fire on the page dim
     tree = [{"attn": {"kp": _sds((2, 33, 4, 8, 16)),
-                      "ckvp": _sds((2, 33, 8, 32))}}]
+                      "latp": _sds((2, 33, 1, 8, 128))}}]
     out = cache_shardings(tree, MESH8)
     assert out[0]["attn"]["kp"].spec == P(None, None, None, None, None)
-    assert out[0]["attn"]["ckvp"].spec == P(None, None, None, None)
+    assert out[0]["attn"]["latp"].spec == P(None, None, None, None, None)
 
 
 def test_paged_pool_mla_leaves_shard():
-    tree = [{"attn": {"ckvp": _sds((2, 64, 8, 32)),
-                      "krp": _sds((2, 64, 8, 16))}}]
+    # the MLA latent pool (count, n_pages, 1, PAGE, lanes)
+    tree = [{"attn": {"latp": _sds((2, 64, 1, 8, 128))}}]
     out = cache_shardings(tree, MESH8)
-    assert out[0]["attn"]["ckvp"].spec == P(None, "data", None, None)
-    assert out[0]["attn"]["krp"].spec == P(None, "data", None, None)
+    assert out[0]["attn"]["latp"].spec == P(None, "data", None, None, None)
 
 
 def test_page_table_sharding():

@@ -56,7 +56,8 @@ def chip_dispatch(monkeypatch):
     host's CPU backend; make the dispatchers choose the chip path."""
     import repro.kernels.dae_gather.ops as gather_ops
     import repro.kernels.flash_attention.ops as flash_ops
-    for mod in (gather_ops, flash_ops):
+    import repro.kernels.grouped_matmul.ops as gmm_ops
+    for mod in (gather_ops, flash_ops, gmm_ops):
         monkeypatch.setattr(mod, "resolve_interpret",
                             lambda i: False if i is None else i)
 
@@ -141,3 +142,41 @@ def test_qwen3_4b_serve_step_fits_one_chip(one_chip, chip_dispatch, chunk):
     assert held < HBM_BYTES, held
     # dae_gather, plus flash_decode_paged when decoding
     assert _kernels(compiled) == (2 if chunk == 1 else 1)
+
+
+@pytest.mark.parametrize("chunk", [1, 32])
+def test_deepseek_v2_lite_serve_step_fits_one_chip(one_chip, chip_dispatch,
+                                                   chunk):
+    """The benchmark cell's step: DeepSeek-V2-Lite at published widths,
+    bf16 weights, experts 0-7 of 64 held, 128 slots x 1,536 positions in
+    pages of 16 (the latent pool of 640 lanes, 6.8 GB): both step widths
+    fit HBM with the pool donated.  The masked decode step runs the
+    latent attention as ``flash_decode_paged`` (one KV head, 16 query
+    heads; one call for the dense layer, one in the expert layers' loop)
+    beside the gather and the held experts' three grouped products; a
+    prefill chunk runs the gather and the grouped products (its
+    attention is ``grouped_chunk_attention``, plain XLA)."""
+    from repro.configs import get_config
+    from repro.models.registry import build_model
+
+    cfg = get_config("deepseek-v2-lite-16b", param_dtype="bfloat16",
+                     kernel_mode="pallas", expert_range=(0, 8))
+    bundle = build_model(cfg)
+    b, page, npb = 128, 16, 96
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(bundle.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        functools.partial(bundle.cache_init_paged, b, 1 + b * npb, page)))
+    step = jax.jit(bundle.prefill_paged, donate_argnums=1)
+    compiled = step.lower(
+        params, cache, _sds((b, chunk), jnp.int32, one_chip),
+        _sds((b,), jnp.int32, one_chip), _sds((b,), jnp.int32, one_chip),
+        _sds((b, npb), jnp.int32, one_chip)).compile()
+    ma = compiled.memory_analysis()
+    held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert held < HBM_BYTES, held
+    assert _kernels(compiled) == (1 + 2 + 3 if chunk == 1 else 1 + 3)
